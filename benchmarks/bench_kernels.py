@@ -43,8 +43,8 @@ def bench_paged_attention():
     n_pages, ps, p_max = 32, 8, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, H, Dh), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (n_pages + 1, ps, Hkv, Dh), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (n_pages + 1, ps, Hkv, Dh), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (n_pages + 1, Hkv, ps, Dh), jnp.float32)
+    v_pool = jax.random.normal(ks[2], (n_pages + 1, Hkv, ps, Dh), jnp.float32)
     # ragged live lengths: 100 / 37 / 8 / 0 tokens
     lengths = jnp.array([100, 37, 8, 0], jnp.int32)
     table = -jnp.ones((B, p_max), jnp.int32)

@@ -582,11 +582,7 @@ def main() -> None:
     for bench in benches:
         if args.only and args.only not in bench.__name__:
             continue
-        t0 = time.time()
-        try:
-            rows = bench()
-        except Exception as e:  # noqa: BLE001
-            rows = [(bench.__name__, (time.time() - t0) * 1e6, f"ERROR {type(e).__name__}: {e}")]
+        rows = bench()
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}")
         all_rows += rows

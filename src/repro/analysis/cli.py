@@ -184,7 +184,7 @@ def analyze_kernels(vmem_budget: int) -> tuple[list[Finding], dict]:
     # paged: scalar-prefetch page tables; the dead-page clamp onto the
     # trailing scratch page must be reachable ONLY via the -1 sentinel
     page_size, n_pages, slots, Bp = 8, 6, 3, 2
-    pool = jax.ShapeDtypeStruct((n_pages + 1, page_size, Hkv, Dh), jnp.float32)
+    pool = jax.ShapeDtypeStruct((n_pages + 1, Hkv, page_size, Dh), jnp.float32)
     qd = jax.ShapeDtypeStruct((Bp, H, Dh), jnp.float32)
     pages_t = jax.ShapeDtypeStruct((Bp, slots), jnp.int32)
     lens_t = jax.ShapeDtypeStruct((Bp,), jnp.int32)

@@ -194,19 +194,17 @@ def _walk(jaxpr, env: dict, ctx: _Ctx, sink: _Sink) -> list[frozenset]:
     return [_taint_of(env, v) for v in jaxpr.outvars]
 
 
-def _axes_from_names(names: dict) -> frozenset:
-    return frozenset(a for axes in names.values() for a in axes)
+def _axes_from_spec(spec) -> frozenset:
+    """Mesh axes a ``PartitionSpec`` splits an operand over."""
+    return frozenset(a for entry in spec if entry is not None for a in ((entry,) if isinstance(entry, str) else entry))
 
 
 def _walk_shard_map(eqn, in_taints, ctx: _Ctx, sink: _Sink) -> list[frozenset]:
-    mesh = eqn.params["mesh"]
-    auto = frozenset(eqn.params.get("auto") or ())
-    manual = frozenset(mesh.axis_names) - auto
+    manual = frozenset(eqn.params["manual_axes"])
     inner = inner_jaxpr(eqn.params["jaxpr"])
-    in_names = eqn.params["in_names"]
     env = {
-        v: t | (_axes_from_names(names) & manual)
-        for v, t, names in zip(inner.invars, in_taints, in_names)
+        v: t | (_axes_from_spec(spec) & manual)
+        for v, t, spec in zip(inner.invars, in_taints, eqn.params["in_specs"])
     }
     sub_ctx = ctx.nest(manual_axes=ctx.manual_axes | manual, path=f"{ctx.path}/body")
     return _walk(inner, env, sub_ctx, sink)

@@ -80,7 +80,7 @@ def find_eqns(jaxpr: ClosedJaxpr | Jaxpr, prim_name: str) -> list[tuple[str, "ob
 def eqn_src(eqn) -> str:
     """``"file.py:123"`` of the user frame that created the eqn ('' if none)."""
     try:
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
     except Exception:
         return ""
     if frame is None:

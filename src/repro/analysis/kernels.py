@@ -56,7 +56,8 @@ class SentinelCheck:
 
 
 def _block_dims(block_shape) -> tuple:
-    return tuple(1 if d is None else int(d) for d in block_shape)
+    """Element extent of each block dim (a squeezed dim spans one element)."""
+    return tuple(getattr(d, "block_size", 1) for d in block_shape)
 
 
 def _grid_points(grid: Sequence[int]) -> tuple[list[tuple], bool]:
@@ -106,7 +107,7 @@ def audit_pallas_eqn(
     block_bytes = 0
     operands = []
     for bm in mappings:
-        sd = bm.array_shape_dtype
+        sd = bm.array_aval
         dims = _block_dims(bm.block_shape)
         nbytes = int(np.prod(dims) * _itemsize(sd.dtype))
         block_bytes += nbytes
@@ -159,7 +160,7 @@ def audit_pallas_eqn(
     # --- block-origin bounds over the grid ---------------------------------
     n_checked = 0
     for op_idx, bm in enumerate(mappings):
-        sd = bm.array_shape_dtype
+        sd = bm.array_aval
         dims = _block_dims(bm.block_shape)
         reserved = next((s for s in sentinels if s.operand == op_idx), None)
         seen_oob = False
@@ -192,7 +193,7 @@ def audit_pallas_eqn(
     # --- sentinel intent ----------------------------------------------------
     for sc in sentinels:
         bm = mappings[sc.operand]
-        sd = bm.array_shape_dtype
+        sd = bm.array_aval
         dims = _block_dims(bm.block_shape)
         leak = miss = None
         for idx in points:

@@ -5,7 +5,7 @@
 * :mod:`repro.dist.collectives` — ring allreduce + error-feedback gradient
   compression.
 * :mod:`repro.dist.sharding` — divisibility-aware PartitionSpec assignment.
-* :mod:`repro.dist.compat` — jax cross-version shims (shard_map, make_mesh).
+* :mod:`repro.dist.compat` — the shard_map and mesh entry points (Auto axes, device prefix).
 """
 
 from repro.dist.collectives import (

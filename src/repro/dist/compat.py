@@ -1,22 +1,14 @@
-"""JAX version-compatibility shims for the distribution layer.
+"""Mesh and shard_map entry points for the distribution layer (jax 0.9).
 
-The repo targets the window jax 0.4.35 .. 0.6.x.  Three APIs moved in that
-window and everything in ``repro.dist`` (and the multi-device tests) must
-run on either side:
-
-* ``shard_map``: ``jax.experimental.shard_map.shard_map(..., check_rep,
-  auto)`` became ``jax.shard_map(..., check_vma, axis_names)``.
-* ``jax.make_mesh`` grew an ``axis_types`` keyword (explicit-sharding work).
-* ``jax.sharding.AxisType`` does not exist on 0.4.x at all.
-
-Keep every version probe here — nothing else in the package may touch
-``jax.experimental`` or feature-sniff jax directly.
+* ``shard_map`` keeps the repo's positional ``(f, mesh, in_specs,
+  out_specs)`` calling convention, with replication checking off by default.
+* ``make_mesh`` pins every axis to ``AxisType.Auto`` (GSPMD sharding
+  propagation, which the step code relies on) and takes a prefix subset of
+  the devices when the host has more than the mesh needs.
 """
 
 from __future__ import annotations
 
-import inspect
-from functools import lru_cache
 from typing import Any, Callable
 
 import jax
@@ -24,49 +16,15 @@ import jax
 __all__ = ["shard_map", "make_mesh"]
 
 
-@lru_cache(maxsize=None)
-def _shard_map_impl() -> tuple[Callable, frozenset]:
-    """Resolve the shard_map entry point and its keyword surface once."""
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-    return fn, frozenset(inspect.signature(fn).parameters)
-
-def shard_map(
-    f: Callable,
-    mesh: Any,
-    in_specs: Any,
-    out_specs: Any,
-    *,
-    check_rep: bool = False,
-    auto: frozenset = frozenset(),
-) -> Callable:
-    """``shard_map`` with the old (0.4.x) calling convention on any jax.
-
-    ``auto`` names mesh axes left to the GSPMD partitioner (partial-manual
-    mode); on new jax this is translated to the ``axis_names`` complement.
-    """
-    fn, params = _shard_map_impl()
-    kwargs: dict[str, Any] = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_rep" in params:
-        kwargs["check_rep"] = check_rep
-    elif "check_vma" in params:
-        kwargs["check_vma"] = check_rep
-    if auto:
-        if "auto" in params:
-            kwargs["auto"] = frozenset(auto)
-        elif "axis_names" in params:
-            kwargs["axis_names"] = set(mesh.axis_names) - set(auto)
-        else:  # no partial-manual support at all: fail loudly, not wrongly
-            raise NotImplementedError("this jax has no partial-auto shard_map")
-    return fn(f, **kwargs)
+def shard_map(f: Callable, mesh: Any, in_specs: Any, out_specs: Any, *, check_rep: bool = False) -> Callable:
+    """``jax.shard_map`` over every axis of ``mesh``; ``check_rep`` maps to
+    ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_rep)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` that (a) tolerates jax versions without
-    ``axis_types`` and (b) uses a prefix subset of devices when the host has
-    more than the mesh needs (plain ``jax.make_mesh`` insists on using all)."""
+    """``jax.make_mesh`` with Auto axes over a prefix subset of devices
+    (plain ``jax.make_mesh`` insists on using all of them)."""
     n = 1
     for s in axis_shapes:
         n *= int(s)
@@ -74,10 +32,9 @@ def make_mesh(axis_shapes, axis_names, *, devices=None):
         avail = jax.devices()
         if len(avail) > n:
             devices = avail[:n]
-    kwargs: dict[str, Any] = {}
-    sig = inspect.signature(jax.make_mesh).parameters
-    if "axis_types" in sig and hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    if devices is not None:
-        kwargs["devices"] = devices
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes),
+        tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )

@@ -132,7 +132,7 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,  # CPU container: interpret; real TPU: False
+    interpret: bool,
 ) -> jnp.ndarray:
     B, Sq, H, Dh = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]

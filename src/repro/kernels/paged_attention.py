@@ -20,19 +20,26 @@ proportional to the slot's LIVE tokens, rounded up to page granularity:
 * GQA is the same index-map trick as the flash kernel: the grid runs over
   B*H query heads and the k/v map picks kv head ``(h // G)``.
 * Variants: sliding-window masking (``window=``) and int8 KV pools with
-  per-(token, head) scales dequantized in-kernel (``k_scale``/``v_scale``).
+  per-(token, head) scales dequantized in-kernel (``k_scale``/``v_scale``):
+  a scale block holds one page's ``(Hkv, page_size)`` scales, the kernel
+  picks its kv head's ``(1, page_size)`` row, and that row multiplies the
+  scores (k) or the probabilities (v), so no block is ever transposed.
 
 Forward-only by contract (like ``flash_attention``): decode never
-differentiates through the cache.  ``interpret=True`` is the CPU-container
-default; on TPU the same call lowers to Mosaic.
+differentiates through the cache.  ``interpret`` is decided by
+``repro.kernels.ops`` (Mosaic on TPU, the interpreter elsewhere).
 
-Layout contract (shared with ``models.attention`` and ``serve.paged``):
-  q          (B, H, Dh)            one query token per slot
-  k/v pool   (n_pages + 1, page_size, Hkv, Dh)   — LAST page is scratch
+Layout contract (shared with ``models.attention`` and ``serve.engine``):
+  q          (B, H, Dh)                          one query token per slot
+  k/v pool   (n_pages + 1, Hkv, page_size, Dh)   LAST page is scratch
+  k/v scale  (n_pages + 1, Hkv, page_size)       int8 pools only
   pages      (B, num_page_slots)   int32 page ids, -1 = unallocated
   lengths    (B,)                  live tokens per slot (0 = empty slot)
 Slot b attends positions ``0 .. lengths[b]-1``; position p lives in pool
-page ``pages[b, p // page_size]`` at offset ``p % page_size``.
+page ``pages[b, p // page_size]`` at offset ``p % page_size``.  Head-major
+pages keep each block's last two dims equal to the pool's
+(``(page_size, Dh)``, and ``(Hkv, page_size)`` for scales), which is what
+Mosaic requires of a block narrower than one (8, 128) tile.
 """
 
 from __future__ import annotations
@@ -52,9 +59,9 @@ def _paged_kernel(
     pages_ref,  # (B, num_page_slots) int32
     len_ref,  # (B,) int32
     # blocks
-    q_ref,  # (1, 1, Dh)
-    k_ref,  # (1, page_size, 1, Dh)
-    v_ref,  # (1, page_size, 1, Dh)
+    q_ref,  # (1, Dh)
+    k_ref,  # (page_size, Dh)
+    v_ref,  # (page_size, Dh)
     *rest,  # [k_scale_ref, v_scale_ref,] o_ref, m_scr, l_scr, acc_scr
     scale: float,
     window: int | None,
@@ -62,6 +69,7 @@ def _paged_kernel(
     page_size: int,
     num_page_slots: int,
     n_heads: int,
+    n_kv_heads: int,
     int8_kv: bool,
 ):
     if int8_kv:
@@ -71,6 +79,7 @@ def _paged_kernel(
     bh = pl.program_id(0)
     j = pl.program_id(1)
     b = bh // n_heads
+    kv_head = (bh % n_heads) // (n_heads // n_kv_heads)
     length = len_ref[b]
 
     @pl.when(j == 0)
@@ -88,16 +97,14 @@ def _paged_kernel(
 
     @pl.when(page_ok)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)  # (1, Dh)
-        if int8_kv:
-            k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        else:
-            k = k_ref[0, :, 0].astype(jnp.float32)  # (page_size, Dh)
-            v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)  # (1, Dh)
+        k = k_ref[...].astype(jnp.float32)  # (page_size, Dh)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (1, page_size)
+        if int8_kv:
+            s = s * _head_row(ks_ref, kv_head, n_kv_heads)  # per-token k scales
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)
         k_pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
@@ -110,8 +117,9 @@ def _paged_kernel(
         p = jnp.exp(s - m_new[:, None])
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
+        pv = p * _head_row(vs_ref, kv_head, n_kv_heads) if int8_kv else p
         acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         m_scr[...] = m_new
 
@@ -120,7 +128,16 @@ def _paged_kernel(
         # l == 0 (empty slot: every page dead) yields zeros, not NaN — the
         # engine ignores inactive slots' outputs.
         denom = jnp.maximum(l_scr[...], 1e-37)[:, None]
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
+
+
+def _head_row(ref, head, n_rows: int):
+    """Row ``head`` of a ``(n_rows, page_size)`` scale block as a
+    ``(1, page_size)`` float32 row, selected by a mask-and-sum over the
+    sublanes rather than a dynamic sublane slice."""
+    block = ref[...].astype(jnp.float32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0)
+    return jnp.sum(jnp.where(rows == head, block, 0.0), axis=0, keepdims=True)
 
 
 @functools.partial(
@@ -129,19 +146,19 @@ def _paged_kernel(
 )
 def paged_attention(
     q: jnp.ndarray,  # (B, H, Dh)
-    k_pool: jnp.ndarray,  # (n_pages + 1, page_size, Hkv, Dh)
-    v_pool: jnp.ndarray,  # (n_pages + 1, page_size, Hkv, Dh)
+    k_pool: jnp.ndarray,  # (n_pages + 1, Hkv, page_size, Dh)
+    v_pool: jnp.ndarray,  # (n_pages + 1, Hkv, page_size, Dh)
     pages: jnp.ndarray,  # (B, num_page_slots) int32
     lengths: jnp.ndarray,  # (B,) int32
-    k_scale: jnp.ndarray | None = None,  # (n_pages + 1, page_size, Hkv) for int8 pools
+    k_scale: jnp.ndarray | None = None,  # (n_pages + 1, Hkv, page_size) for int8 pools
     v_scale: jnp.ndarray | None = None,
     *,
     window: int | None = None,
     softcap: float = 0.0,
-    interpret: bool = True,  # CPU container: interpret; real TPU: False
+    interpret: bool,
 ) -> jnp.ndarray:
     B, H, Dh = q.shape
-    n_pages_p1, page_size, Hkv, _ = k_pool.shape
+    n_pages_p1, Hkv, page_size, _ = k_pool.shape
     num_page_slots = pages.shape[1]
     G = H // Hkv
     scratch_page = n_pages_p1 - 1
@@ -162,22 +179,22 @@ def paged_attention(
         live = (p >= 0) & (j * page_size < len_ref[b])
         if window is not None:
             live &= (j + 1) * page_size > len_ref[b] - window
-        return (jnp.where(live, p, scratch_page), 0, h // G, 0)
+        return (jnp.where(live, p, scratch_page), h // G, 0, 0)
 
     def scale_index(bh, j, pages_ref, len_ref):
-        p, _, hkv, _ = kv_index(bh, j, pages_ref, len_ref)
-        return (p, 0, hkv)
+        page, _, _, _ = kv_index(bh, j, pages_ref, len_ref)
+        return (page, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, Dh), q_index),
-        pl.BlockSpec((1, page_size, 1, Dh), kv_index),
-        pl.BlockSpec((1, page_size, 1, Dh), kv_index),
+        pl.BlockSpec((None, 1, Dh), q_index),
+        pl.BlockSpec((None, None, page_size, Dh), kv_index),
+        pl.BlockSpec((None, None, page_size, Dh), kv_index),
     ]
     operands = [qh, k_pool, v_pool]
     if int8_kv:
         in_specs += [
-            pl.BlockSpec((1, page_size, 1), scale_index),
-            pl.BlockSpec((1, page_size, 1), scale_index),
+            pl.BlockSpec((None, Hkv, page_size), scale_index),
+            pl.BlockSpec((None, Hkv, page_size), scale_index),
         ]
         operands += [k_scale, v_scale]
 
@@ -189,13 +206,14 @@ def paged_attention(
         page_size=page_size,
         num_page_slots=num_page_slots,
         n_heads=H,
+        n_kv_heads=Hkv,
         int8_kv=int8_kv,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B * H, num_page_slots),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, Dh), q_index),
+        out_specs=pl.BlockSpec((None, 1, Dh), q_index),
         scratch_shapes=[
             pltpu.VMEM((1,), jnp.float32),  # m (running max)
             pltpu.VMEM((1,), jnp.float32),  # l (running denom)

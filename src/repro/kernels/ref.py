@@ -52,11 +52,11 @@ def flash_attention_ref(
 
 def paged_attention_ref(
     q: jnp.ndarray,  # (B, H, Dh)
-    k_pool: jnp.ndarray,  # (n_pages + 1, page_size, Hkv, Dh)
-    v_pool: jnp.ndarray,  # (n_pages + 1, page_size, Hkv, Dh)
+    k_pool: jnp.ndarray,  # (n_pages + 1, Hkv, page_size, Dh)
+    v_pool: jnp.ndarray,  # (n_pages + 1, Hkv, page_size, Dh)
     pages: jnp.ndarray,  # (B, num_page_slots) int32, -1 = unallocated
     lengths: jnp.ndarray,  # (B,) int32 live tokens per slot
-    k_scale: jnp.ndarray | None = None,  # (n_pages + 1, page_size, Hkv) int8 pools
+    k_scale: jnp.ndarray | None = None,  # (n_pages + 1, Hkv, page_size) int8 pools
     v_scale: jnp.ndarray | None = None,
     *,
     window: int | None = None,
@@ -68,7 +68,7 @@ def paged_attention_ref(
     at offset ``p % page_size``; it attends positions 0..lengths[b]-1 (its
     query sits at position lengths[b]-1)."""
     B, H, Dh = q.shape
-    n_pages_p1, page_size, Hkv, _ = k_pool.shape
+    n_pages_p1, Hkv, page_size, _ = k_pool.shape
     S = pages.shape[1] * page_size
     G = H // Hkv
     pos = jnp.arange(S)
@@ -77,13 +77,13 @@ def paged_attention_ref(
     off = pos % page_size
 
     def gather(pool):
-        return pool[safe, off[None, :]].astype(jnp.float32)  # (B, S, Hkv, Dh)
+        return pool[safe, :, off[None, :]].astype(jnp.float32)  # (B, S, Hkv, Dh)
 
     k = gather(k_pool)
     v = gather(v_pool)
     if k_pool.dtype == jnp.int8:
-        k = k * k_scale[safe, off[None, :]].astype(jnp.float32)[..., None]
-        v = v * v_scale[safe, off[None, :]].astype(jnp.float32)[..., None]
+        k = k * gather(k_scale[..., None])
+        v = v * gather(v_scale[..., None])
     qg = q.reshape(B, 1, Hkv, G, Dh).astype(jnp.float32)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * (Dh**-0.5)
     if softcap > 0:

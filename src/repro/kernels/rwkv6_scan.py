@@ -95,7 +95,8 @@ def rwkv6_scan(
     u: jnp.ndarray,  # (H, D)
     s0: jnp.ndarray | None = None,  # (B, H, D, D)
     chunk: int = 32,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     B, T, H, D = r.shape
     chunk = min(chunk, T)

@@ -34,7 +34,8 @@ def weighted_accum(
     g: jnp.ndarray,
     scale: jnp.ndarray | float,
     block: int = 4096,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """acc + scale * g (elementwise, fp32 math), any matching shapes."""
     assert acc.shape == g.shape, (acc.shape, g.shape)
@@ -65,6 +66,6 @@ def weighted_accum(
     return out.reshape(-1)[:n].reshape(orig_shape)
 
 
-def weighted_accum_tree(acc_tree, g_tree, scale, interpret: bool = True):
+def weighted_accum_tree(acc_tree, g_tree, scale, *, interpret: bool):
     """Apply over a full gradient pytree."""
     return jax.tree.map(lambda a, g: weighted_accum(a, g, scale, interpret=interpret), acc_tree, g_tree)

@@ -1,13 +1,17 @@
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(
+    [os.environ.get("XLA_FLAGS", ""), "--xla_force_host_platform_device_count=512"]
+).strip()
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the device
+The statement above MUST run before any other import (jax locks the device
 count at first backend init): 512 host devices back the 16x16 single-pod and
-2x16x16 multi-pod production meshes. Never set this flag globally — smoke
-tests and benchmarks see 1 device.
+2x16x16 multi-pod production meshes.  It appends to the caller's XLA_FLAGS
+rather than replacing them.  Never set this flag globally — smoke tests and
+benchmarks see 1 device, and a process that drives a chip never imports this
+module.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun                    # all cells, both meshes
@@ -203,9 +207,7 @@ def run_cell(
         lowered = jitted.lower(*plan.abstract_args)
         compiled = lowered.compile()
         ma = compiled.memory_analysis()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax<=0.4.x: list of per-device dicts
-            ca = ca[0] if ca else {}
+        ca = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
         colls = collective_stats(hlo)
         per_dev_bytes = ma.argument_size_in_bytes + ma.temp_size_in_bytes + ma.output_size_in_bytes

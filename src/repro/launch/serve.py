@@ -10,7 +10,8 @@ adaptive-router experiment end-to-end.
 
 ``--attn-impl`` selects the attention path end-to-end: ``naive``/``blocked``/
 ``flash`` pick the prefill implementation over the dense per-slot cache
-(``flash`` runs the Pallas flash kernel, interpret-mode on CPU), and
+(``flash`` runs the Pallas flash kernel: compiled on TPU, interpreted
+elsewhere), and
 ``paged`` switches the whole KV layout to the shared page pool + Pallas
 ragged paged-decode kernel — decode cost proportional to live tokens, and
 ``prompt + max_gen`` may exceed ``--max-seq`` (pool-bounded instead).

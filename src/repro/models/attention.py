@@ -7,7 +7,8 @@ Three interchangeable implementations (numerically equivalent, tested):
   with online softmax. Bounded memory; this is what the dry-run lowers for
   large shapes, and what XLA sees for the roofline.
 * ``flash``   — Pallas TPU kernel (``repro.kernels.flash_attention``),
-  interpret-mode on CPU. Wired lazily to avoid import cycles.
+  compiled on TPU and interpreted elsewhere (``repro.kernels.ops``). Wired
+  lazily to avoid import cycles.
 
 GQA avoids materializing repeated KV heads by grouping query heads:
 q is viewed as (B, S, Hkv, G, Dh) and contracted against k (B, S, Hkv, Dh).
@@ -28,6 +29,7 @@ from repro.models.rope import apply_rope
 
 __all__ = [
     "PagedLayout",
+    "paged_put",
     "init_attention",
     "attention_train",
     "attention_decode",
@@ -310,6 +312,14 @@ def attention_decode(
     return linear(out.astype(x.dtype), p["wo"]), new_cache
 
 
+def paged_put(pool: jnp.ndarray, dest: jnp.ndarray, off: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    """Scatter per-token rows into a paged pool: row n lands in page
+    ``dest[n]`` at offset ``off[n]``.  ``rows`` is (N, Hkv, Dh) for a k/v pool
+    (n_pages+1, Hkv, page_size, Dh) or (N, Hkv) for an int8 scale pool
+    (n_pages+1, Hkv, page_size)."""
+    return pool.at[dest, :, off].set(rows.astype(pool.dtype))
+
+
 def _decode_paged(
     p: dict,
     x: jnp.ndarray,
@@ -319,8 +329,9 @@ def _decode_paged(
 ) -> tuple[jnp.ndarray, dict]:
     """One-token decode against a paged KV pool.
 
-    cache: {"k_pool","v_pool": (n_pages+1, page_size, Hkv, Dh) [+ int8 scale
-    pools], "pages": (B, P_max) int32, "index": (B,)}.  The new token's K/V
+    cache: {"k_pool","v_pool": (n_pages+1, Hkv, page_size, Dh) [+ int8 scale
+    pools (n_pages+1, Hkv, page_size)], "pages": (B, P_max) int32,
+    "index": (B,)}.  The new token's K/V
     is scattered into the slot's page for position ``index`` (slots without
     an allocated page — inactive slots — write the trailing scratch page),
     then the ragged paged-attention kernel attends positions 0..index.
@@ -333,7 +344,7 @@ def _decode_paged(
     q, k_new, v_new = _qkv(p, x, cfg, positions)
 
     k_pool = cache["k_pool"]
-    page_size = k_pool.shape[1]
+    page_size = k_pool.shape[2]
     scratch_page = k_pool.shape[0] - 1
     bidx = jnp.arange(B)
     pslot = jnp.clip(index // page_size, 0, pages.shape[1] - 1)
@@ -343,21 +354,18 @@ def _decode_paged(
     dest = jnp.where(pg >= 0, pg, scratch_page)
     off = index % page_size
 
-    def put(pool, new):  # new: (B, 1, Hkv, ...) -> row-wise scatter into pages
-        return pool.at[dest, off].set(new[:, 0].astype(pool.dtype))
-
     int8_kv = k_pool.dtype == jnp.int8
     k_scale = v_scale = None
     if int8_kv:
         k_q, k_s = _quant_int8(k_new)
         v_q, v_s = _quant_int8(v_new)
-        k_pool = put(k_pool, k_q)
-        v_pool = put(cache["v_pool"], v_q)
-        k_scale = put(cache["k_scale_pool"], k_s)
-        v_scale = put(cache["v_scale_pool"], v_s)
+        k_pool = paged_put(k_pool, dest, off, k_q[:, 0])
+        v_pool = paged_put(cache["v_pool"], dest, off, v_q[:, 0])
+        k_scale = paged_put(cache["k_scale_pool"], dest, off, k_s[:, 0])
+        v_scale = paged_put(cache["v_scale_pool"], dest, off, v_s[:, 0])
     else:
-        k_pool = put(k_pool, k_new)
-        v_pool = put(cache["v_pool"], v_new)
+        k_pool = paged_put(k_pool, dest, off, k_new[:, 0])
+        v_pool = paged_put(cache["v_pool"], dest, off, v_new[:, 0])
 
     from repro.kernels import ops as kops  # lazy: avoid import cycle
 
@@ -453,13 +461,14 @@ def init_paged_kv_cache(cfg: ModelConfig, layout: PagedLayout, dtype=None) -> di
     The page table ("pages") and position clock ("index") are tracked once at
     the cache's top level — every layer shares the same allocation pattern."""
     dt = dtype or cfg.dtype("compute")
-    shape = (layout.n_pages + 1, layout.page_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (layout.n_pages + 1, cfg.n_kv_heads, layout.page_size, cfg.head_dim)
     if cfg.kv_cache_dtype == "int8":
+        scale_shape = (layout.n_pages + 1, cfg.n_kv_heads, layout.page_size)
         return {
             "k_pool": jnp.zeros(shape, jnp.int8),
             "v_pool": jnp.zeros(shape, jnp.int8),
-            "k_scale_pool": jnp.zeros(shape[:3], jnp.bfloat16),
-            "v_scale_pool": jnp.zeros(shape[:3], jnp.bfloat16),
+            "k_scale_pool": jnp.zeros(scale_shape, jnp.bfloat16),
+            "v_scale_pool": jnp.zeros(scale_shape, jnp.bfloat16),
         }
     return {"k_pool": jnp.zeros(shape, dt), "v_pool": jnp.zeros(shape, dt)}
 

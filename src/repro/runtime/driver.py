@@ -55,9 +55,9 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, smoke_config
@@ -206,6 +206,8 @@ class ElasticTrainer:
         self.epoch = 0
         self.agg_index = 0  # aggregations already consumed in the current epoch
         self.losses: list[float] = []
+        self.grad_norms: list[float] = []  # global gradient norm of each step, before clipping
+        self.step_s: list[float] = []  # wall seconds of each step (the first compiles)
         self.epoch_log: list[dict] = []  # completed epochs (BENCH reads this)
         self.membership_log: list[dict] = []
         self.straggler_flags = 0
@@ -240,7 +242,15 @@ class ElasticTrainer:
         self.w_max = max(cfg.w_max or auto, int(np.max(self.alloc)))
         n_dev = len(jax.devices())
         shape = (n, 1) if 1 < n <= n_dev else (1, 1)
+        if n > 1 and shape == (1, 1):
+            # more ranks than devices: every rank's rows run on one device
+            # (the step is allocation-invariant, so the math is unchanged)
+            self._log(f"[mesh] {n} ranks folded onto a 1x1 mesh ({n_dev} device(s) visible)")
         self.mesh = make_test_mesh(shape, ("data", "model"))
+        # rank-major batch rows live on their rank's device: while-mode's
+        # shard_map takes them as they are, and masked mode's vmap over ranks
+        # is partitioned by GSPMD instead of running every rank on one device
+        self._batch_sharding = NamedSharding(self.mesh, P("data"))
         self.scfg = HeteroStepConfig(
             w_max=self.w_max,
             micro_bs=cfg.micro_bs,
@@ -281,14 +291,17 @@ class ElasticTrainer:
         self.straggler = StragglerMonitor(n)
 
     def _reshard_state(self) -> None:
-        """Place the persistent state for the current mesh.  Under
-        ``fsdp='gather'`` the state lives sharded per ``state_specs`` — after
-        a membership change the old shard layout no longer matches, so the
+        """Place the persistent state for the current mesh, where the step
+        returns it: sharded per ``state_specs`` under ``fsdp='gather'``,
+        replicated otherwise.  Placed as the step's output will be, the state
+        never reaches the step in a second layout, so the step compiles once.
+        After a membership change the old layout no longer matches, so the
         whole tree is re-placed (jax reshards across mesh shapes in one
         device_put per leaf)."""
-        if self.scfg.fsdp != "gather":
-            return
-        sspecs = state_specs(self.state, self.mesh, fsdp=True, fsdp_axes=self.scfg.fsdp_axes)
+        if self.scfg.fsdp == "gather":
+            sspecs = state_specs(self.state, self.mesh, fsdp=True, fsdp_axes=self.scfg.fsdp_axes)
+        else:
+            sspecs = jax.tree.map(lambda _: P(), self.state)
         self.state = jax.tree.map(lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)), self.state, sspecs)
 
     # -- checkpoint metadata ----------------------------------------------------
@@ -554,6 +567,10 @@ class ElasticTrainer:
             "first_loss": self.losses[0] if self.losses else None,
             "last_loss": self.losses[-1] if self.losses else None,
             "loss_drop": (self.losses[0] - self.losses[-1]) if self.losses else None,
+            "losses": list(self.losses),
+            "grad_norms": list(self.grad_norms),
+            "step_s": list(self.step_s),
+            "mesh": self._mesh_summary(),
             "final_allocation": np.asarray(self.alloc).tolist(),
             "n_workers": len(self.gpus),
             "gpus": list(self.gpus),
@@ -582,16 +599,17 @@ class ElasticTrainer:
         for batch_np in self.batcher.epoch(self.epoch, alloc, start=self.agg_index):
             if self.step_i >= cfg.steps or self._event_due():
                 return  # leave agg_index where it is; caller decides
-            batch = {
-                "inputs": jnp.asarray(batch_np["inputs"]),
-                "targets": jnp.asarray(batch_np["targets"]),
-                "alloc": jnp.asarray(batch_np["alloc"]),
-            }
+            batch = jax.device_put(
+                {k: batch_np[k] for k in ("inputs", "targets", "alloc")}, self._batch_sharding
+            )
             t0 = time.perf_counter()
             self.state, metrics = self.step_fn(self.state, batch)
             loss = float(metrics["loss"])  # device sync: wall below is honest
-            self.timing.record_step(time.perf_counter() - t0, batch_np["alloc"])
+            dt = time.perf_counter() - t0
+            self.timing.record_step(dt, batch_np["alloc"])
             self.losses.append(loss)
+            self.grad_norms.append(float(metrics["grad_norm"]))
+            self.step_s.append(dt)
             self.step_i += 1
             self.agg_index += 1
             steps_run += 1
@@ -689,6 +707,17 @@ class ElasticTrainer:
         self.epoch += 1
         self.agg_index = 0
         self._timing_from_agg = 0
+
+    def _mesh_summary(self) -> dict:
+        """The mesh the step ran on, as built (not as requested): a fold of
+        several ranks onto one device shows here as a 1x1 shape."""
+        devs = list(self.mesh.devices.flat)
+        return {
+            "shape": list(self.mesh.devices.shape),
+            "axes": list(self.mesh.axis_names),
+            "devices": len({d.id for d in devs}),
+            "platform": devs[0].platform,
+        }
 
     def _epoch_summary(self) -> dict:
         times = [e["epoch_s"] for e in self.epoch_log]

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import PagedLayout, decode_step, init_cache, init_params, prefill
+from repro.models.attention import paged_put
 from repro.models.config import ModelConfig
 from repro.serve.paged import PagePool
 
@@ -168,14 +169,15 @@ class ServeEngine:
             splice row-wise exactly like the dense insert."""
             if "k_pool" in big_layer:
                 out = {}
+                W = dest.shape[0]
                 for src, dst in _POOL_KEYS:
                     if dst not in big_layer:
                         continue
                     pool, vals = big_layer[dst], small_layer[src]
-                    if stacked:  # (R, 1, S, ...) -> scatter (R, W, ...)
-                        out[dst] = pool.at[:, dest, offs].set(vals[:, 0, : dest.shape[0]].astype(pool.dtype))
+                    if stacked:  # (R, 1, S, ...) -> scatter (R, W, ...) layer by layer
+                        out[dst] = jax.vmap(lambda p, v: paged_put(p, dest, offs, v))(pool, vals[:, 0, :W])
                     else:
-                        out[dst] = pool.at[dest, offs].set(vals[0, : dest.shape[0]].astype(pool.dtype))
+                        out[dst] = paged_put(pool, dest, offs, vals[0, :W])
                 return out
             if stacked:
                 return jax.tree.map(lambda g, s: g.at[:, b].set(s[:, 0].astype(g.dtype)), big_layer, small_layer)

@@ -261,7 +261,7 @@ def test_pallas_vmem_budget_flagged():
 def _paged_trace(n_pages=6, page_size=8, slots=3, B=2, H=4, Hkv=2, Dh=16):
     from repro.kernels.paged_attention import paged_attention
 
-    pool = jax.ShapeDtypeStruct((n_pages + 1, page_size, Hkv, Dh), jnp.float32)
+    pool = jax.ShapeDtypeStruct((n_pages + 1, Hkv, page_size, Dh), jnp.float32)
     q = jax.ShapeDtypeStruct((B, H, Dh), jnp.float32)
     pages = jax.ShapeDtypeStruct((B, slots), jnp.int32)
     lens = jax.ShapeDtypeStruct((B,), jnp.int32)

@@ -24,6 +24,9 @@ def run_subprocess(code: str, n_devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC
+    # the child models virtual host devices; it must never reach for a chip
+    # this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         env=env,
@@ -441,3 +444,22 @@ def test_param_specs_shapes_divisible():
                 continue
             size = {"data": 16, "model": 16}[ax] if isinstance(ax, str) else 16 * 16
             assert leaf.shape[i] % size == 0, (path, leaf.shape, spec)
+
+
+@pytest.mark.parametrize("mode", ["while", "masked"])
+def test_driver_result_names_the_four_device_mesh(mode):
+    """On a forced 4-device host, 4 ranks get a 4x1 mesh of distinct devices
+    and the result names it."""
+    out = run_subprocess(
+        f"""
+        import json
+        from repro.launch import train
+        r = train.main(["--arch", "smollm-360m", "--smoke", "--n-workers", "4", "--steps", "2",
+                        "--total-micro", "8", "--micro-bs", "1", "--seq", "16", "--mode", "{mode}",
+                        "--policy", "static", "--static-ratio", "4,2,1,1"])
+        print("MESH=" + json.dumps(r["mesh"]))
+        """,
+        n_devices=4,
+    )
+    line = next(ln for ln in out.splitlines() if ln.startswith("MESH="))
+    assert json.loads(line[5:]) == {"shape": [4, 1], "axes": ["data", "model"], "devices": 4, "platform": "cpu"}

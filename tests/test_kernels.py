@@ -1,22 +1,41 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode on
+the CPU backend, through the ``repro.kernels.ops`` wrappers)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ops import weighted_accum, weighted_accum_tree
-from repro.kernels.paged_attention import paged_attention
+from repro.analysis.jaxpr_walk import find_eqns
+from repro.kernels import flash_attention as fa
+from repro.kernels.ops import (
+    flash_attention,
+    paged_attention,
+    pallas_interpret,
+    rwkv6_scan,
+    weighted_accum,
+    weighted_accum_tree,
+)
 from repro.kernels.ref import (
     flash_attention_ref,
     paged_attention_ref,
     rwkv6_scan_ref,
     weighted_accum_ref,
 )
-from repro.kernels.rwkv6_scan import rwkv6_scan
 
 KEY = jax.random.PRNGKey(0)
+
+
+def test_ops_interpret_on_cpu_backend():
+    """The backend decides: on CPU every dispatch wrapper interprets."""
+    assert jax.default_backend() == "cpu" and pallas_interpret()
+    q = jax.ShapeDtypeStruct((2, 4, 16), jnp.float32)
+    pool = jax.ShapeDtypeStruct((5, 2, 8, 16), jnp.float32)
+    pages = jax.ShapeDtypeStruct((2, 2), jnp.int32)
+    lens = jax.ShapeDtypeStruct((2,), jnp.int32)
+    closed = jax.make_jaxpr(paged_attention)(q, pool, pool, pages, lens)
+    calls = [eqn for _, eqn in find_eqns(closed, "pallas_call")]
+    assert calls and all(eqn.params["interpret"] for eqn in calls)
 
 
 # ---------------------------------------------------------------------------
@@ -41,8 +60,8 @@ def test_flash_attention_matches_ref_fp32(case):
     q = jax.random.normal(ks[0], (B, Sq, H, Dh), jnp.float32)
     k = jax.random.normal(ks[1], (B, Sk, Hkv, Dh), jnp.float32)
     v = jax.random.normal(ks[2], (B, Sk, Hkv, Dh), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, window=window, softcap=softcap,
-                          q_offset=qoff, block_q=bq, block_kv=bk)
+    out = fa.flash_attention(q, k, v, causal=causal, window=window, softcap=softcap, q_offset=qoff,
+                             block_q=bq, block_kv=bk, interpret=pallas_interpret())
     ref = flash_attention_ref(q, k, v, causal=causal, window=window, softcap=softcap, q_offset=qoff)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -52,7 +71,7 @@ def test_flash_attention_bf16():
     q = jax.random.normal(ks[0], (1, 128, 4, 64), jnp.bfloat16)
     k = jax.random.normal(ks[1], (1, 128, 2, 64), jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, 128, 2, 64), jnp.bfloat16)
-    out = flash_attention(q, k, v, block_q=64, block_kv=64)
+    out = fa.flash_attention(q, k, v, block_q=64, block_kv=64, interpret=pallas_interpret())
     ref = flash_attention_ref(q, k, v)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=3e-2, atol=3e-2
@@ -71,8 +90,8 @@ def _paged_fixture(lengths, n_pages=12, page_size=4, p_max=6, H=4, Hkv=2, Dh=64,
     B = len(lengths)
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, H, Dh), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (n_pages + 1, page_size, Hkv, Dh), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (n_pages + 1, page_size, Hkv, Dh), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (n_pages + 1, Hkv, page_size, Dh), jnp.float32)
+    v_pool = jax.random.normal(ks[2], (n_pages + 1, Hkv, page_size, Dh), jnp.float32)
     order = np.random.default_rng(shuffle).permutation(n_pages)
     table = np.full((B, p_max), -1, np.int32)
     nxt = 0
@@ -112,7 +131,7 @@ def test_paged_attention_empty_slot_outputs_zero():
 
 
 def test_paged_attention_int8_dequant_matches_ref():
-    def quant(x):
+    def quant(x):  # (P, Hkv, ps, Dh) -> int8 pool + (P, Hkv, ps) scales
         amax = jnp.max(jnp.abs(x), axis=-1)
         scale = jnp.maximum(amax / 127.0, 1e-8)
         qv = jnp.clip(jnp.round(x / scale[..., None]), -127, 127).astype(jnp.int8)
@@ -135,8 +154,8 @@ def test_paged_attention_matches_flash_oracle_contiguous():
     out = paged_attention(q, k_pool, v_pool, table, lens)
     # materialize the contiguous K/V from the (shuffled) pages
     tb = np.asarray(table[0])
-    k = jnp.concatenate([k_pool[p] for p in tb if p >= 0], axis=0)[:L]
-    v = jnp.concatenate([v_pool[p] for p in tb if p >= 0], axis=0)[:L]
+    k = jnp.concatenate([k_pool[p].transpose(1, 0, 2) for p in tb if p >= 0], axis=0)[:L]
+    v = jnp.concatenate([v_pool[p].transpose(1, 0, 2) for p in tb if p >= 0], axis=0)[:L]
     ref = flash_attention_ref(q[:, None], k[None], v[None], causal=True, q_offset=L - 1)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0, 0]), rtol=2e-5, atol=2e-5)
 

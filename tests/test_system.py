@@ -2,6 +2,7 @@
 checkpoint/restart resumes exactly, serving decodes."""
 
 import json
+import logging
 
 import jax
 import numpy as np
@@ -392,3 +393,26 @@ def test_serve_cli_decodes():
     assert res["completed"] == 4
     assert res["gen_tokens"] > 0 and res["throughput_tok_per_s"] > 0
     assert 0 < res["slot_utilization"] <= 1
+
+
+def test_driver_result_names_the_folded_mesh():
+    """More ranks than devices fold onto a 1x1 mesh, and the result says so."""
+    result = train_cli.main(
+        ["--arch", "smollm-360m", "--smoke", "--n-workers", "2", "--steps", "2",
+         "--total-micro", "4", "--micro-bs", "1", "--seq", "16"]
+    )
+    assert result["mesh"] == {"shape": [1, 1], "axes": ["data", "model"], "devices": 1, "platform": "cpu"}
+    assert len(result["losses"]) == len(result["step_s"]) == 2
+
+
+def test_train_step_compiles_once(caplog):
+    """The driver places the state where the step returns it, so every step
+    after the first reuses the first step's executable."""
+    with caplog.at_level(logging.WARNING, logger="jax"), jax.log_compiles(True):
+        result = train_cli.main(
+            ["--arch", "smollm-360m", "--smoke", "--n-workers", "2", "--steps", "3",
+             "--total-micro", "4", "--micro-bs", "1", "--seq", "16"]
+        )
+    compiles = [r for r in caplog.records if r.getMessage().startswith("Compiling jit(step)")]
+    assert len(compiles) == 1
+    assert len(result["grad_norms"]) == 3 and all(g > 0 for g in result["grad_norms"])
