@@ -19,13 +19,14 @@ from repro.dist.collectives import (
     ring_allreduce_tree,
     ring_reduce_scatter,
 )
-from repro.dist.hetero_step import HeteroStepConfig, build_train_step, init_train_state
+from repro.dist.hetero_step import HeteroStepConfig, build_train_step, init_train_state, micro_passes
 from repro.dist.sharding import cache_specs, param_specs, state_specs
 
 __all__ = [
     "HeteroStepConfig",
     "build_train_step",
     "init_train_state",
+    "micro_passes",
     "ring_allreduce",
     "ring_allreduce_tree",
     "ring_all_gather",

@@ -61,7 +61,7 @@ from repro.optim import (
     sgd_update,
 )
 
-__all__ = ["HeteroStepConfig", "init_train_state", "build_train_step"]
+__all__ = ["HeteroStepConfig", "init_train_state", "build_train_step", "micro_passes"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -222,6 +222,17 @@ def _while_accum(params, inputs, targets, alloc, cfg, scfg):
         init = (jnp.zeros((), jnp.int32),) + carry
         carry = jax.lax.while_loop(cond, body, init)[1:]
     return carry
+
+
+def micro_passes(scfg: HeteroStepConfig, alloc) -> int:
+    """Microbatch forward/backward passes one step computes for ``alloc``:
+    masked mode scans all W_max slots on every rank, while-mode loops each
+    rank's own (clamped) allocation.  Against ``sum(alloc)``, the passes
+    trained, it counts the padding the mode pays."""
+    a = np.asarray(alloc)
+    if scfg.mode == "masked":
+        return a.size * scfg.w_max
+    return int(np.minimum(a, scfg.w_max).sum())
 
 
 def _while_grads(params, inputs, targets, alloc, cfg, scfg):

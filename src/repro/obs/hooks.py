@@ -7,11 +7,12 @@ every hook returns after one attribute check — observability off means the
 instrumented code paths do no measurable extra work and produce
 bit-identical results.
 
-Timestamps are virtual: the trainer's clock advances by modeled (simulated
-or measured-and-attributed) epoch durations, the serve clock by decode
-ticks (or the bench's analytic tick-cost model).  Under seeded simulated
-timing both the Perfetto trace and the metrics snapshot are deterministic
-byte-for-byte.
+Timestamps are virtual: the trainer's clock advances by modeled aggregation
+makespans (simulated timing) or by each step's measured wall time, the serve
+clock by decode ticks (or the bench's analytic tick-cost model).  Under
+seeded simulated timing both the Perfetto trace and the metrics snapshot are
+deterministic byte-for-byte.  A real run's host and device timeline is the
+JAX profiler's: the driver's ``driver.*`` spans (``repro.runtime.driver``).
 """
 
 from __future__ import annotations
@@ -51,29 +52,36 @@ class _ObsBase:
 
 class TrainObs(_ObsBase):
     """ElasticTrainer hooks: per-worker compute/wait/collective spans per
-    aggregation, allocation-share counters, membership/checkpoint instants,
-    fault windows as spans, straggler flags, collective bytes."""
+    aggregation (simulated timing) or one span per measured step, allocation
+    counters, membership/checkpoint instants, fault windows as spans,
+    straggler flags, collective bytes, microbatch passes."""
 
     def __init__(self, trace_out=None, metrics_out=None, tracer=None, metrics=None) -> None:
         super().__init__(trace_out, metrics_out, tracer, metrics)
-        self._vt = 0.0  # virtual seconds: sum of modeled aggregation makespans
+        self._vt = 0.0  # virtual seconds: modeled makespans or measured step walls, summed
         self._step_t: dict[int, float] = {}  # global step -> vt at step start
         self._windows: list[tuple[str, int, int | None, dict]] = []  # open fault windows
 
-    def on_epoch(self, epoch, step_end, steps_run, t_s, t_c, alloc, gpus, per_agg, coll_bytes) -> None:
-        """One finished epoch measurement.  ``t_s``: per-worker seconds — per
-        aggregation when ``per_agg`` (simulated), whole-epoch accumulated
-        otherwise (measured; split evenly over ``steps_run``)."""
+    def on_epoch(self, epoch, step_end, steps_run, t_s, t_c, alloc, gpus, per_agg, coll_bytes, step_s=()) -> None:
+        """One finished epoch measurement.  Simulated (``per_agg``): ``t_s``
+        holds each worker's modeled seconds per aggregation.  Measured:
+        ``step_s`` holds the wall seconds of each of the ``steps_run`` steps;
+        ``t_s`` is then one wall clock split over ranks by allocation, which
+        says nothing of any rank, so no per-worker time is drawn from it."""
         if not self.enabled or steps_run <= 0:
             return
-        n = len(t_s)
-        t_agg = [float(t) if per_agg else float(t) / steps_run for t in t_s]
-        T = max(t_agg)
         m = self.metrics
         if m is not None:
             m.counter("train.steps").inc(steps_run)
             m.counter("train.epochs").inc()
             m.counter("train.collective_bytes").inc(steps_run * coll_bytes)
+        if not per_agg:
+            self._measured_steps(epoch, step_end - steps_run, step_s, alloc)
+            return
+        n = len(t_s)
+        t_agg = [float(t) for t in t_s]
+        T = max(t_agg)
+        if m is not None:
             agg_h = m.histogram("train.agg_makespan_s")
             comp_h = m.histogram("train.worker_compute_s")
             wait_h = m.histogram("train.worker_wait_s")
@@ -102,6 +110,29 @@ class TrainObs(_ObsBase):
                 if t_c > 0.0:
                     tr.span(track, "collective", t0 + T, t_c, {"bytes": coll_bytes})
             self._vt = t0 + T + t_c
+
+    def _measured_steps(self, epoch, step0, step_s, alloc) -> None:
+        """One ``step`` span per measured step on the ``train/steps`` track,
+        and its wall time in ``train.agg_makespan_s``."""
+        tr = self.tracer
+        if tr.enabled:
+            tr.counter("train/allocation", "allocation", self._vt, {f"w{i}": int(a) for i, a in enumerate(alloc)})
+        for k, wall in enumerate(step_s):
+            wall = float(wall)
+            if self.metrics is not None:
+                self.metrics.histogram("train.agg_makespan_s").record(wall)
+            if tr.enabled:
+                self._step_t[step0 + k] = self._vt
+                tr.span("train/steps", "step", self._vt, wall, {"step": step0 + k, "epoch": int(epoch)})
+            self._vt += wall
+
+    def on_micro_passes(self, computed, trained) -> None:
+        """One step's microbatch passes: computed by the step, and trained
+        (the allocation's; the rest is padding)."""
+        if self.metrics is None:
+            return
+        self.metrics.counter("train.micro_passes_computed").inc(computed)
+        self.metrics.counter("train.micro_passes_trained").inc(trained)
 
     def on_flags(self, epoch, step_end, flags) -> None:
         if not self.enabled:

@@ -56,6 +56,7 @@ import time
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -69,7 +70,7 @@ from repro.core import (
     static_allocation,
 )
 from repro.data import HeteroBatcher, SyntheticLM
-from repro.dist import HeteroStepConfig, build_train_step, init_train_state
+from repro.dist import HeteroStepConfig, build_train_step, init_train_state, micro_passes
 from repro.dist.collectives import ring_allreduce_bytes
 from repro.dist.sharding import state_specs
 from repro.obs import TrainObs
@@ -208,6 +209,10 @@ class ElasticTrainer:
         self.losses: list[float] = []
         self.grad_norms: list[float] = []  # global gradient norm of each step, before clipping
         self.step_s: list[float] = []  # wall seconds of each step (the first compiles)
+        # microbatch passes the steps computed, and the allocation's share of
+        # them (masked mode computes w_max passes per rank, padding included)
+        self.micro_passes_computed = 0
+        self.micro_passes_trained = 0
         self.epoch_log: list[dict] = []  # completed epochs (BENCH reads this)
         self.membership_log: list[dict] = []
         self.straggler_flags = 0
@@ -227,10 +232,14 @@ class ElasticTrainer:
         self._param_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(self.state["params"]))
         if self.mgr and cfg.resume and self.mgr.latest_step() is not None:
             self._restore()
-        self._build()
-        self._reshard_state()
+        self._rebuild()
 
     # -- membership-dependent construction ------------------------------------
+
+    def _rebuild(self) -> None:
+        with TraceAnnotation("driver.rebuild"):
+            self._build()
+            self._reshard_state()
 
     def _build(self) -> None:
         """(Re)build everything that depends on the current membership:
@@ -523,8 +532,7 @@ class ElasticTrainer:
             # re-point the speed model / monitor at the new fleet
             self._rebuild_monitoring()
         else:
-            self._build()
-            self._reshard_state()
+            self._rebuild()
 
     def _schedule_recovery(self, gpus: list[str], at_step: int) -> None:
         """Insert dynamic ``add`` events for healed outage victims, each on
@@ -591,40 +599,64 @@ class ElasticTrainer:
     def _run_epoch(self) -> None:
         """Train until the epoch completes, an event comes due, or the step
         budget runs out.  Controller updates happen only on COMPLETE epoch
-        measurements."""
+        measurements.
+
+        Host spans on the profiler's clock (recorded only while a JAX profile
+        is taken): ``driver.batch`` around each pull from the batcher, the
+        look-ahead pull that the budget or an event then drops included;
+        ``driver.step`` (``step_num``) around each step, holding
+        ``driver.put`` / ``driver.dispatch`` / ``driver.sync`` /
+        ``driver.record``; ``driver.epoch_end`` around the epoch boundary."""
         cfg = self.cfg
         alloc = np.asarray(self.alloc)
         n_agg = self.batcher.aggregations_per_epoch(alloc)
         steps_run = 0
-        for batch_np in self.batcher.epoch(self.epoch, alloc, start=self.agg_index):
+        batches = self.batcher.epoch(self.epoch, alloc, start=self.agg_index)
+        while True:
+            with TraceAnnotation("driver.batch"):
+                batch_np = next(batches, None)
+            if batch_np is None:
+                break
             if self.step_i >= cfg.steps or self._event_due():
                 return  # leave agg_index where it is; caller decides
-            batch = jax.device_put(
-                {k: batch_np[k] for k in ("inputs", "targets", "alloc")}, self._batch_sharding
-            )
-            t0 = time.perf_counter()
-            self.state, metrics = self.step_fn(self.state, batch)
-            loss = float(metrics["loss"])  # device sync: wall below is honest
-            dt = time.perf_counter() - t0
-            self.timing.record_step(dt, batch_np["alloc"])
-            self.losses.append(loss)
-            self.grad_norms.append(float(metrics["grad_norm"]))
-            self.step_s.append(dt)
-            self.step_i += 1
-            self.agg_index += 1
-            steps_run += 1
-            # the metadata (controller state_dict + log tail) is only worth
-            # serializing on steps that actually save
-            if self.mgr and self.mgr.is_due(self.step_i):
-                self.mgr.save(self.step_i, self.state, metadata=self._metadata())
-                self.obs.on_checkpoint(self.step_i)
-            if self.step_i % cfg.log_every == 0 or self.step_i == 1:
-                self._log(
-                    f"step {self.step_i:5d} loss {loss:.4f} "
-                    f"tokens {float(metrics['tokens']):.0f} alloc {alloc.tolist()}"
-                )
+            with StepTraceAnnotation("driver.step", step_num=self.step_i):
+                with TraceAnnotation("driver.put"):
+                    batch = jax.device_put(
+                        {k: batch_np[k] for k in ("inputs", "targets", "alloc")}, self._batch_sharding
+                    )
+                t0 = time.perf_counter()
+                with TraceAnnotation("driver.dispatch"):
+                    self.state, metrics = self.step_fn(self.state, batch)
+                with TraceAnnotation("driver.sync"):
+                    loss = float(metrics["loss"])  # device sync: wall below is honest
+                    grad_norm = float(metrics["grad_norm"])
+                dt = time.perf_counter() - t0
+                with TraceAnnotation("driver.record"):
+                    self.timing.record_step(dt, batch_np["alloc"])
+                    self.losses.append(loss)
+                    self.grad_norms.append(grad_norm)
+                    self.step_s.append(dt)
+                    computed = micro_passes(self.scfg, batch_np["alloc"])
+                    trained = int(batch_np["alloc"].sum())
+                    self.micro_passes_computed += computed
+                    self.micro_passes_trained += trained
+                    self.obs.on_micro_passes(computed, trained)
+                    self.step_i += 1
+                    self.agg_index += 1
+                    steps_run += 1
+                    # the metadata (controller state_dict + log tail) is only worth
+                    # serializing on steps that actually save
+                    if self.mgr and self.mgr.is_due(self.step_i):
+                        self.mgr.save(self.step_i, self.state, metadata=self._metadata())
+                        self.obs.on_checkpoint(self.step_i)
+                    if self.step_i % cfg.log_every == 0 or self.step_i == 1:
+                        self._log(
+                            f"step {self.step_i:5d} loss {loss:.4f} "
+                            f"tokens {float(metrics['tokens']):.0f} alloc {alloc.tolist()}"
+                        )
         if self.agg_index >= n_agg:
-            self._finish_epoch(steps_run, n_agg)
+            with TraceAnnotation("driver.epoch_end"):
+                self._finish_epoch(steps_run, n_agg)
 
     def _finish_epoch(self, steps_run: int, n_agg: int) -> None:
         """Epoch boundary: read the timing source, update the controller
@@ -688,6 +720,7 @@ class ElasticTrainer:
                     self.gpus,
                     per_agg=self.simulated,
                     coll_bytes=ring_allreduce_bytes(self._param_bytes, len(self.gpus)),
+                    step_s=self.step_s[-steps_run:],
                 )
                 self.obs.on_flags(self.epoch, self.step_i, flags)
             if self.cfg.policy == "adaptive":
@@ -696,8 +729,7 @@ class ElasticTrainer:
                     # allocation outgrew the step buffers: rebuild with a
                     # deeper w_max instead of tripping the host check
                     self._log(f"[capacity] allocation {self.alloc.tolist()} > w_max={self.w_max}; rebuilding")
-                    self._build()
-                    self._reshard_state()
+                    self._rebuild()
         else:
             # a resume landed mid-epoch: the pre-restart wall time is gone,
             # so skip ONE controller update rather than feed a truncated

@@ -373,6 +373,43 @@ def test_train_obs_epoch_spans_and_fault_windows(tmp_path):
     assert snap["histograms"]["train.worker_wait_s"]["count"] == 16
 
 
+def test_train_obs_measured_steps(tmp_path):
+    """Measured timing: one ``step`` span per step with its wall time on the
+    virtual clock, and no per-worker split of one fused step's wall."""
+    obs = TrainObs(trace_out=str(tmp_path / "t.json"), metrics_out=str(tmp_path / "m.json"))
+    alloc, gpus = np.array([3, 1]), ["rtx2080ti", "rtx2080ti"]
+    obs.on_epoch(0, 3, 3, [0.6, 0.2], 0.0, alloc, gpus, per_agg=False, coll_bytes=10, step_s=[0.25, 0.5, 0.25])
+    obs.on_fault(3, "slow@3:1*2~1", 1)
+    obs.on_epoch(1, 4, 1, [0.3, 0.1], 0.0, alloc, gpus, per_agg=False, coll_bytes=10, step_s=[0.5])
+    obs.close()
+    evs = json.loads((tmp_path / "t.json").read_text())["traceEvents"]
+    steps = [e for e in evs if e.get("name") == "step"]
+    assert [e["args"]["step"] for e in steps] == [0, 1, 2, 3]
+    assert [e["ts"] for e in steps] == pytest.approx([0.0, 0.25e6, 0.75e6, 1.0e6])
+    assert [e["dur"] for e in steps] == pytest.approx([0.25e6, 0.5e6, 0.25e6, 0.5e6])
+    assert not {"compute", "wait", "collective"} & {e.get("name") for e in evs}
+    (window,) = [e for e in evs if e["name"].startswith("fault window")]
+    assert window["ts"] == pytest.approx(1.0e6) and window["dur"] == pytest.approx(0.5e6)
+    snap = json.loads((tmp_path / "m.json").read_text())
+    assert snap["counters"]["train.steps"] == 4
+    assert snap["histograms"]["train.agg_makespan_s"]["count"] == 4
+    assert "train.worker_compute_s" not in snap["histograms"]
+    assert "train.worker_wait_s" not in snap["histograms"]
+
+
+def test_train_obs_micro_pass_counters(tmp_path):
+    obs = TrainObs(metrics_out=str(tmp_path / "m.json"))
+    obs.on_micro_passes(8, 4)
+    obs.on_micro_passes(8, 4)
+    obs.close()
+    snap = json.loads((tmp_path / "m.json").read_text())
+    assert snap["counters"]["train.micro_passes_computed"] == 16
+    assert snap["counters"]["train.micro_passes_trained"] == 8
+    off = TrainObs()
+    off.on_micro_passes(8, 4)  # disabled: no registry, no error
+    assert off.metrics is None
+
+
 def test_disabled_obs_bundles_do_no_work():
     obs = TrainObs()  # no outputs -> disabled
     assert not obs.enabled
