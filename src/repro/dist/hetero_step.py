@@ -12,11 +12,13 @@ padding.  Two numerically identical executions of the same math:
   a rank allocated 2 microbatches does 2 forward/backwards, not W_max), then
   the partial (grad_sum, loss_sum, token_sum) are reduced across ranks with
   ``psum`` or our :func:`~repro.dist.collectives.ring_allreduce`.
-* ``mode="masked"`` — plain GSPMD arithmetic masking: scan over the W_max
-  slots, vmap over ranks, weight each slot by ``1[j < alloc[r]]``.  Runs
-  anywhere (including 1 device) and stays legal when parameters are sharded
-  over the allocation axis with per-microbatch FSDP gathers, where
-  while-mode is forbidden — see :meth:`HeteroStepConfig.validate`.
+* ``mode="masked"`` — plain GSPMD arithmetic masking: loop over the first
+  ``max(alloc)`` slots (the same trip count on every rank; slots past every
+  rank's allocation are skipped, not multiplied by zero), vmap over ranks,
+  weight each slot by ``1[j < alloc[r]]``.  Runs anywhere (including 1
+  device) and stays legal when parameters are sharded over the allocation
+  axis with per-microbatch FSDP gathers, where while-mode is forbidden —
+  see :meth:`HeteroStepConfig.validate`.
 
 While-mode additionally supports ``fsdp="gather"``: params and optimizer
 state LIVE sharded over ``fsdp_axes`` (ZeRO-style, specs from
@@ -112,8 +114,8 @@ class HeteroStepConfig:
         with small allocations would stop participating while big ranks
         still wait on them: a deadlock on real hardware.  ``fsdp="gather"``
         hoists the gather OUT of the loops (one per step, uniform across
-        ranks) and is therefore legal; so is masked mode (same trip count
-        everywhere, masked arithmetic)."""
+        ranks) and is therefore legal; so is masked mode (``max(alloc)``
+        trips on every rank, masked arithmetic)."""
         axis_names = tuple(mesh.axis_names)
         if self.alloc_axis not in axis_names:
             raise ValueError(f"alloc_axis {self.alloc_axis!r} not in mesh axes {axis_names}")
@@ -176,23 +178,28 @@ def _zero_carry(params, grad_dtype):
 
 
 def _masked_grads(params, inputs, targets, alloc, cfg, scfg):
-    """Scan the W_max slots; vmap ranks; mask pays w_max trips everywhere."""
+    """Loop the first ``max(alloc)`` slots; vmap ranks; mask each slot.
+
+    The trip count is one replicated scalar, so every rank runs it in
+    lockstep (legal under per-microbatch FSDP gathers); a rank's slots past
+    its own allocation but below the maximum are masked to zero.  Slots past
+    every rank's allocation would only add zeros, so the loop stops there."""
     grad_fn = _grad_fn(cfg, scfg)
     gdt = jnp.dtype(scfg.grad_dtype)
     W = inputs.shape[1]
-    mask = (jnp.arange(W)[None, :] < alloc[:, None]).astype(jnp.float32)  # (R, W)
     vgrad = jax.vmap(grad_fn, in_axes=(None, 0, 0))
 
-    def slot(carry, xs):
+    def slot(j, carry):
         gsum, lsum, tsum = carry
-        x, y, m = xs  # x/y: (R, mb, S); m: (R,)
+        x = jax.lax.dynamic_index_in_dim(inputs, j, axis=1, keepdims=False)  # (R, mb, S)
+        y = jax.lax.dynamic_index_in_dim(targets, j, axis=1, keepdims=False)
+        m = (j < alloc).astype(jnp.float32)  # (R,)
         (ls, tk), g = vgrad(params, x, y)
         gsum = jax.tree.map(lambda a, b: a + jnp.tensordot(m, b.astype(jnp.float32), axes=1).astype(a.dtype), gsum, g)
-        return (gsum, lsum + (m * ls).sum(), tsum + (m * tk).sum()), None
+        return gsum, lsum + (m * ls).sum(), tsum + (m * tk).sum()
 
-    xs = (inputs.transpose(1, 0, 2, 3), targets.transpose(1, 0, 2, 3), mask.T)
-    (gsum, lsum, tsum), _ = jax.lax.scan(slot, _zero_carry(params, gdt), xs)
-    return gsum, lsum, tsum
+    n = jnp.minimum(jnp.max(alloc), W)
+    return jax.lax.fori_loop(0, n, slot, _zero_carry(params, gdt))
 
 
 def _while_accum(params, inputs, targets, alloc, cfg, scfg):
@@ -226,12 +233,12 @@ def _while_accum(params, inputs, targets, alloc, cfg, scfg):
 
 def micro_passes(scfg: HeteroStepConfig, alloc) -> int:
     """Microbatch forward/backward passes one step computes for ``alloc``:
-    masked mode scans all W_max slots on every rank, while-mode loops each
-    rank's own (clamped) allocation.  Against ``sum(alloc)``, the passes
-    trained, it counts the padding the mode pays."""
+    masked mode runs ``max(alloc)`` slots (clamped to w_max) on every rank,
+    while-mode loops each rank's own (clamped) allocation.  Against
+    ``sum(alloc)``, the passes trained, it counts the padding the mode pays."""
     a = np.asarray(alloc)
     if scfg.mode == "masked":
-        return a.size * scfg.w_max
+        return a.size * min(int(a.max(initial=0)), scfg.w_max)
     return int(np.minimum(a, scfg.w_max).sum())
 
 

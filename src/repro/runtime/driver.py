@@ -210,7 +210,7 @@ class ElasticTrainer:
         self.grad_norms: list[float] = []  # global gradient norm of each step, before clipping
         self.step_s: list[float] = []  # wall seconds of each step (the first compiles)
         # microbatch passes the steps computed, and the allocation's share of
-        # them (masked mode computes w_max passes per rank, padding included)
+        # them (masked mode computes max(alloc) passes per rank, padding included)
         self.micro_passes_computed = 0
         self.micro_passes_trained = 0
         self.epoch_log: list[dict] = []  # completed epochs (BENCH reads this)

@@ -183,6 +183,18 @@ def test_analyzer_agrees_with_validate(mode, fsdp, collective, smoke_setup):
     assert not _errors(findings)
 
 
+def test_analyze_train_judges_masked_steps_uniform():
+    """Masked mode's accumulation loop takes its trip count from the batch's
+    allocation (its largest entry, one scalar for every rank); the analysis
+    CLI's train target still judges both masked combinations uniform."""
+    from repro.analysis.cli import analyze_train
+
+    findings, meta = analyze_train(make_mesh((1, 1), ("data", "model")))
+    assert not _errors(findings), [f.message for f in _errors(findings)]
+    for fsdp in (False, True):
+        assert meta[f"train:masked-fsdp={fsdp}-psum"]["verdict"] == "uniform"
+
+
 # ---------------------------------------------------------------------------
 # specs audit (satellite 3): every config x every declared mesh, zero errors
 # ---------------------------------------------------------------------------

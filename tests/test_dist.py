@@ -83,6 +83,109 @@ def test_while_equals_masked_equals_reference():
 
 
 @pytest.mark.slow
+def test_masked_below_buffer_depth_equals_reference():
+    """Masked mode loops only up to the largest allocation: with every
+    allocation below w_max, masked, masked over FSDP-sharded params and
+    while mode each equal the per-rank manual loop and its AdamW update; an
+    all-zero allocation trains nothing and stays finite."""
+    out = run_subprocess(
+        """
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding
+        from repro.models import ModelConfig
+        from repro.dist import HeteroStepConfig, build_train_step, init_train_state
+        from repro.dist.hetero_step import _micro_loss_sum
+        from repro.dist.sharding import state_specs
+        from repro.launch.mesh import make_test_mesh
+        from repro.optim import AdamWConfig, adamw_update
+
+        mesh = make_test_mesh((4, 2), ("data", "model"))
+        cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
+                          n_heads=4, n_kv_heads=2, d_ff=64, vocab_size=101,
+                          compute_dtype="float32", remat=False)
+        kw = dict(w_max=4, micro_bs=8, seq_len=16, alloc_axis="data")
+        modes = {
+            "masked": HeteroStepConfig(mode="masked", **kw),
+            "masked-fsdp": HeteroStepConfig(mode="masked", fsdp=True, **kw),
+            "while": HeteroStepConfig(mode="while", **kw),
+        }
+        state = init_train_state(cfg, modes["masked"], jax.random.PRNGKey(0))
+        specs = state_specs(state, mesh, fsdp=True, fsdp_axes=("data",))
+        sharded = jax.tree.map(lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), state, specs)
+        assert any(not x.sharding.is_fully_replicated for x in jax.tree.leaves(sharded["params"]))
+        R, W, mb, S = 4, 4, 8, 16
+        inputs = jax.random.randint(jax.random.PRNGKey(7), (R, W, mb, S), 0, 101)
+        targets = jax.random.randint(jax.random.PRNGKey(8), (R, W, mb, S), 0, 101)
+
+        def run(name, alloc):
+            start = sharded if name == "masked-fsdp" else state
+            batch = {"inputs": inputs, "targets": targets, "alloc": jnp.array(alloc, jnp.int32)}
+            return build_train_step(cfg, modes[name], mesh)(jax.tree.map(lambda x: x.copy(), start), batch)
+
+        alloc = [1, 2, 1, 0]  # max 2 < w_max 4
+        gf = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg, modes["masked"]), has_aux=True)
+        toks, lsum, gsum = 0.0, 0.0, jax.tree.map(jnp.zeros_like, state["params"])
+        for r in range(R):
+            for j in range(alloc[r]):
+                (ls, tk), g = gf(state["params"], inputs[r, j], targets[r, j])
+                toks += float(tk); lsum += float(ls)
+                gsum = jax.tree.map(jnp.add, gsum, g)
+        grads = jax.tree.map(lambda g: g / toks, gsum)
+        ref_params, _ = adamw_update(grads, state["opt"], state["params"], 1e-3, AdamWConfig())
+        for name in modes:
+            s, m = run(name, alloc)
+            np.testing.assert_allclose(float(m["loss"]), lsum / toks, rtol=1e-5, err_msg=name)
+            assert float(m["tokens"]) == toks, name
+            d = max(jax.tree.leaves(jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
+                                                 s["params"], ref_params)))
+            assert d < 1e-5, (name, d)
+        for name in modes:
+            s, m = run(name, [0, 0, 0, 0])
+            assert float(m["tokens"]) == 0.0, name
+            vals = [float(v) for v in m.values()] + [float(jnp.abs(x).max()) for x in jax.tree.leaves(s)]
+            assert all(np.isfinite(v) for v in vals), name
+        print("OK")
+        """
+    )
+    assert "OK" in out
+
+
+def test_masked_grads_stop_at_the_largest_allocation():
+    """One rank, w_max 8, allocation 4: the masked accumulation equals an
+    explicit scan over the four live slots, bit for bit."""
+    from repro.dist.hetero_step import HeteroStepConfig, _grad_fn, _masked_grads, _zero_carry
+    from repro.models import ModelConfig, transformer
+
+    cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
+                      d_ff=64, vocab_size=101, compute_dtype="bfloat16", remat=False)
+    scfg = HeteroStepConfig(w_max=8, micro_bs=2, seq_len=16, mode="masked")
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    inputs = jax.random.randint(jax.random.PRNGKey(7), (1, 8, 2, 16), 0, 101)
+    targets = jax.random.randint(jax.random.PRNGKey(8), (1, 8, 2, 16), 0, 101)
+    alloc = jnp.array([4], jnp.int32)
+    got = jax.jit(lambda p, x, y, a: _masked_grads(p, x, y, a, cfg, scfg))(params, inputs, targets, alloc)
+
+    vgrad = jax.vmap(_grad_fn(cfg, scfg), in_axes=(None, 0, 0))
+
+    def four_slots(p, x, y):
+        def slot(carry, xs):
+            gsum, lsum, tsum = carry
+            xj, yj = xs
+            m = jnp.ones((1,), jnp.float32)
+            (ls, tk), g = vgrad(p, xj, yj)
+            gsum = jax.tree.map(lambda a, b: a + jnp.tensordot(m, b.astype(jnp.float32), axes=1), gsum, g)
+            return (gsum, lsum + (m * ls).sum(), tsum + (m * tk).sum()), None
+
+        xs = (x[:, :4].transpose(1, 0, 2, 3), y[:, :4].transpose(1, 0, 2, 3))
+        return jax.lax.scan(slot, _zero_carry(p, jnp.float32), xs)[0]
+
+    want = jax.jit(four_slots)(params, inputs, targets)
+    assert float(got[2]) == 4 * 2 * 16
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.slow
 def test_while_gather_fsdp_equals_masked_equals_reference():
     """The tentpole: while-mode with fsdp='gather' (state sharded, ONE
     all-gather per step, gradients reduce-scattered back) is numerically the

@@ -100,13 +100,15 @@ def test_profiler_leaves_losses_and_grad_norms_bit_identical(traced, untraced):
 @pytest.mark.parametrize(
     "mode,alloc,w_max,want",
     [
-        ("masked", [3, 1], 4, 8),  # every rank scans every slot
-        ("masked", [2, 0, 1], 3, 9),  # a rank with no microbatch still scans
+        ("masked", [3, 1], 4, 6),  # every rank runs the largest allocation
+        ("masked", [2, 0, 1], 3, 6),  # a rank with no microbatch still runs it
         ("while", [3, 1], 4, 4),  # each rank loops its own allocation
         ("while", [2, 0, 1], 3, 3),
-        ("masked", [4], 8, 8),  # the benchmark's one-rank cell: half the passes are padding
-        ("masked", [0, 0], 2, 4),
-        ("while", [5, 1], 4, 5),  # an allocation past the buffers is clamped to w_max
+        ("masked", [4], 8, 4),  # the benchmark's one-rank cell: no slot past the allocation
+        ("masked", [0, 0], 2, 0),
+        ("masked", [4, 2, 1, 1], 4, 16),  # largest allocation at w_max: every slot, as before
+        ("masked", [5, 1], 4, 8),  # an allocation past the buffers is clamped to w_max
+        ("while", [5, 1], 4, 5),
         ("while", [0, 0], 2, 0),
     ],
 )
@@ -118,7 +120,8 @@ def test_micro_passes(mode, alloc, w_max, want):
 def test_masked_driver_counts_its_padding(untraced):
     tr = untraced
     per_step = micro_passes(tr.scfg, tr.alloc)
-    assert per_step == 2 * tr.w_max == 8
+    assert list(tr.alloc) == [3, 1] and tr.w_max == 4
+    assert per_step == 2 * 3  # two ranks, each running the largest allocation
     assert (tr.micro_passes_computed, tr.micro_passes_trained) == (3 * per_step, 3 * 4)
 
 
@@ -202,10 +205,12 @@ def test_phases_only_inside_steps_and_batches_outside(long_traced):
 
 
 def test_counters_follow_the_allocation_through_a_rebuild(long_traced):
-    """Masked mode: two ranks at w_max 4 for six steps, then three ranks at
-    the rebuilt depth; every step trains the whole C=4 allocation."""
+    """Masked mode: two ranks at allocation [3, 1] for six steps, then three
+    ranks at [2, 1, 1]; each rank runs the largest allocation, and every step
+    trains the whole C=4 allocation."""
     tr, _, snap = long_traced
-    want = 6 * 2 * 4 + 2 * 3 * tr.w_max
+    assert list(tr.alloc) == [2, 1, 1]
+    want = 6 * 2 * 3 + 2 * 3 * 2
     assert (tr.micro_passes_computed, tr.micro_passes_trained) == (want, 8 * 4)
     assert snap["counters"]["train.micro_passes_computed"] == want
     assert snap["counters"]["train.micro_passes_trained"] == 8 * 4
