@@ -17,10 +17,10 @@ TPU-native design (not a CUDA port):
   fully-masked kv blocks are skipped with ``pl.when`` (the roofline win of
   causal flash: ~2x fewer MACs than the masked dense form).
 
-The kernel is forward-only; training uses the differentiable blocked-jnp
-implementation (`models/attention.py`), serving uses this kernel. (A Pallas
-backward is a recorded beyond-paper TODO; XLA's own fused attention already
-covers the training path well on TPU.)
+The kernel is forward-only and serves prefill.  Training on a TPU runs the
+shipped splash kernel, which has its own backward
+(`kernels/splash_attention.py`); elsewhere, and for what that kernel cannot
+express, training runs the autodiffed blocked-jnp scan (`models/attention.py`).
 """
 
 from __future__ import annotations

@@ -163,9 +163,9 @@ def test_masked_grads_stop_at_the_largest_allocation():
     inputs = jax.random.randint(jax.random.PRNGKey(7), (1, 8, 2, 16), 0, 101)
     targets = jax.random.randint(jax.random.PRNGKey(8), (1, 8, 2, 16), 0, 101)
     alloc = jnp.array([4], jnp.int32)
-    got = jax.jit(lambda p, x, y, a: _masked_grads(p, x, y, a, cfg, scfg))(params, inputs, targets, alloc)
+    got = jax.jit(lambda p, x, y, a: _masked_grads(p, x, y, a, cfg, scfg, "blocked"))(params, inputs, targets, alloc)
 
-    vgrad = jax.vmap(_grad_fn(cfg, scfg), in_axes=(None, 0, 0))
+    vgrad = jax.vmap(_grad_fn(cfg, scfg, "blocked"), in_axes=(None, 0, 0))
 
     def four_slots(p, x, y):
         def slot(carry, xs):

@@ -214,3 +214,14 @@ def test_counters_follow_the_allocation_through_a_rebuild(long_traced):
     assert (tr.micro_passes_computed, tr.micro_passes_trained) == (want, 8 * 4)
     assert snap["counters"]["train.micro_passes_computed"] == want
     assert snap["counters"]["train.micro_passes_trained"] == 8 * 4
+
+
+def test_attention_paths_on_the_trainer_and_in_the_snapshot(long_traced):
+    """On the CPU every attention layer trains on the blocked scan; the
+    trainer and the ``--metrics-out`` snapshot both carry the tally, through
+    a rebuild that traced the step again."""
+    tr, _, snap = long_traced
+    n = tr.model_cfg.n_layers
+    assert tr.attention_paths == {"splash": 0, "blocked": n}
+    assert snap["gauges"]["train.attention_path.blocked"]["value"] == n
+    assert snap["gauges"]["train.attention_path.splash"]["value"] == 0
