@@ -77,14 +77,4 @@ def bench_rwkv6_scan():
     return [("kernel_rwkv6_scan_128", us, f"tpu_flops={flops:.3g}")]
 
 
-def bench_weighted_accum():
-    from repro.kernels.ops import weighted_accum
-
-    n = 1 << 20
-    a = jax.random.normal(jax.random.PRNGKey(0), (n,))
-    g = jax.random.normal(jax.random.PRNGKey(1), (n,))
-    us = _time(lambda *x: weighted_accum(*x, 0.5), a, g, iters=2)
-    return [("kernel_weighted_accum_1M", us, f"hbm_bytes={3*4*n} (fused: 1r+1r+1w)")]
-
-
-ALL = [bench_flash_attention, bench_paged_attention, bench_rwkv6_scan, bench_weighted_accum]
+ALL = [bench_flash_attention, bench_paged_attention, bench_rwkv6_scan]

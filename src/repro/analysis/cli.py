@@ -167,7 +167,6 @@ def analyze_kernels(vmem_budget: int) -> tuple[list[Finding], dict]:
     from repro.kernels.flash_attention import flash_attention
     from repro.kernels.paged_attention import paged_attention
     from repro.kernels.rwkv6_scan import rwkv6_scan
-    from repro.kernels.weighted_accum import weighted_accum
 
     findings: list[Finding] = []
     meta: dict = {}
@@ -224,18 +223,6 @@ def analyze_kernels(vmem_budget: int) -> tuple[list[Finding], dict]:
     f, m = audit_traced(closed, "kernels:rwkv6_scan", vmem_budget=vmem_budget)
     findings += f
     meta["rwkv6_scan"] = m
-
-    # weighted_accum: scalar-prefetch scale
-    acc = jax.ShapeDtypeStruct((3, 512), jnp.float32)
-    scale = np.ones((1,), np.float32)
-    closed = jax.make_jaxpr(
-        lambda a, g: weighted_accum(a, g, 1.0, block=512, interpret=True)
-    )(acc, acc)
-    f, m = audit_traced(
-        closed, "kernels:weighted_accum", vmem_budget=vmem_budget, scalar_args=(scale,)
-    )
-    findings += f
-    meta["weighted_accum"] = m
     return findings, meta
 
 

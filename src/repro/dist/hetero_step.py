@@ -50,7 +50,6 @@ from repro.dist import compat
 from repro.dist.collectives import all_gather_params, reduce_scatter_tree, ring_allreduce_tree
 from repro.dist.sharding import state_specs
 from repro.kernels import ops as kops
-from repro.models import attention as attn_lib
 from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.optim import (
@@ -132,7 +131,7 @@ class HeteroStepConfig:
         return self
 
 
-def _micro_loss_sum(params, inputs, targets, cfg: ModelConfig, scfg: HeteroStepConfig, attn_impl: str = "blocked"):
+def _micro_loss_sum(params, inputs, targets, cfg: ModelConfig, attn_impl: str = "blocked"):
     """Summed (not averaged) loss of ONE microbatch.
 
     Returns ``(loss_sum, token_count)``; dividing accumulated ``loss_sum``
@@ -141,7 +140,6 @@ def _micro_loss_sum(params, inputs, targets, cfg: ModelConfig, scfg: HeteroStepC
     weight ranks by their allocation).  MoE auxiliary losses are folded in
     per token so they renormalize identically.
     """
-    del scfg  # static shapes already baked into the batch
     loss, aux = transformer.loss_fn(params, {"inputs": inputs, "targets": targets}, cfg, attn_impl=attn_impl)
     tokens = aux["tokens"]
     return loss * tokens, tokens
@@ -167,27 +165,21 @@ def init_train_state(
 # ---------------------------------------------------------------------------
 
 
-def _grad_fn(cfg: ModelConfig, scfg: HeteroStepConfig, attn_impl: str):
-    def f(params, x, y):
-        return _micro_loss_sum(params, x, y, cfg, scfg, attn_impl)
-
-    return jax.value_and_grad(f, has_aux=True)
-
-
 def _zero_carry(params, grad_dtype):
     gz = jax.tree.map(lambda p: jnp.zeros(p.shape, grad_dtype), params)
     return gz, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
 
 
-def _masked_grads(params, inputs, targets, alloc, cfg, scfg, attn_impl):
+def _masked_grads(params, inputs, targets, alloc, grad_fn, gdt):
     """Loop the first ``max(alloc)`` slots; vmap ranks; mask each slot.
+
+    ``grad_fn(params, x, y) -> ((loss_sum, tokens), grads)`` is one
+    microbatch's; ``gdt`` is the accumulation dtype.
 
     The trip count is one replicated scalar, so every rank runs it in
     lockstep (legal under per-microbatch FSDP gathers); a rank's slots past
     its own allocation but below the maximum are masked to zero.  Slots past
     every rank's allocation would only add zeros, so the loop stops there."""
-    grad_fn = _grad_fn(cfg, scfg, attn_impl)
-    gdt = jnp.dtype(scfg.grad_dtype)
     W = inputs.shape[1]
     vgrad = jax.vmap(grad_fn, in_axes=(None, 0, 0))
 
@@ -204,15 +196,13 @@ def _masked_grads(params, inputs, targets, alloc, cfg, scfg, attn_impl):
     return jax.lax.fori_loop(0, n, slot, _zero_carry(params, gdt))
 
 
-def _while_accum(params, inputs, targets, alloc, cfg, scfg, attn_impl):
+def _while_accum(params, inputs, targets, alloc, grad_fn, gdt):
     """Per-local-rank while loops with dynamic trip counts (NO collectives).
 
     Runs inside shard_map over ``scfg.alloc_axis``; ``inputs`` is the local
     (R_local, W, mb, S) block.  Each rank does exactly ``alloc[r]`` grads
     and returns its LOCAL (grad_sum, loss_sum, token_sum).
     """
-    grad_fn = _grad_fn(cfg, scfg, attn_impl)
-    gdt = jnp.dtype(scfg.grad_dtype)
     R_local, W = inputs.shape[:2]
     alloc = jnp.minimum(alloc, W)
     carry = _zero_carry(params, gdt)
@@ -244,9 +234,9 @@ def micro_passes(scfg: HeteroStepConfig, alloc) -> int:
     return int(np.minimum(a, scfg.w_max).sum())
 
 
-def _while_grads(params, inputs, targets, alloc, cfg, scfg, attn_impl):
+def _while_grads(params, inputs, targets, alloc, grad_fn, gdt, scfg):
     """While-mode with replicated params: local loops, then allreduce."""
-    gsum, lsum, tsum = _while_accum(params, inputs, targets, alloc, cfg, scfg, attn_impl)
+    gsum, lsum, tsum = _while_accum(params, inputs, targets, alloc, grad_fn, gdt)
     # cross-rank reduction: the ONLY collective in the step — the paper's
     # plug-in point.  Scalars always ride psum; the gradient tree may take
     # the explicit ring.
@@ -260,7 +250,7 @@ def _while_grads(params, inputs, targets, alloc, cfg, scfg, attn_impl):
     return gsum, lsum, tsum
 
 
-def _gathered_while_grads(shards, inputs, targets, alloc, cfg, scfg, pspecs, attn_impl):
+def _gathered_while_grads(shards, inputs, targets, alloc, grad_fn, gdt, scfg, pspecs):
     """While-mode over SHARDED params (``fsdp="gather"``).
 
     ``shards`` is the local param-shard tree laid out per ``pspecs``.  The
@@ -272,7 +262,7 @@ def _gathered_while_grads(shards, inputs, targets, alloc, cfg, scfg, pspecs, att
     """
     ring = scfg.collective == "ring"
     params = all_gather_params(shards, pspecs, use_ring=ring)
-    gsum, lsum, tsum = _while_accum(params, inputs, targets, alloc, cfg, scfg, attn_impl)
+    gsum, lsum, tsum = _while_accum(params, inputs, targets, alloc, grad_fn, gdt)
     ax = scfg.alloc_axis
     gsum = reduce_scatter_tree(gsum, pspecs, reduce_axes=(ax,), use_ring=ring)
     lsum = jax.lax.psum(lsum, ax)
@@ -314,14 +304,14 @@ def build_train_step(
     explicit in/out shardings (dryrun, serving planners).
 
     Attention is chosen here from the mesh (:func:`train_attention`), and
-    the returned callable's ``attention_paths`` holds, once it has traced,
-    the tally of the path each attention layer lowered to
-    (``{"splash": 32, "blocked": 0}`` for smollm-360m on one TPU chip).
+    the one microbatch gradient function every accumulation body runs is
+    built here with it.
     """
     scfg.validate(mesh)
     platform = mesh.devices.flat[0].platform
     attn_impl = train_attention(platform, mesh.devices.size, scfg.mode)
-    attention_paths = {}
+    grad_fn = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg, attn_impl), has_aux=True)
+    gdt = jnp.dtype(scfg.grad_dtype)
     lr_fn = lr_fn or constant(scfg.lr)
     if scfg.optimizer == "adamw":
         ocfg = opt_cfg or AdamWConfig()
@@ -344,7 +334,7 @@ def build_train_step(
 
     def global_grads(params, inputs, targets, alloc):
         if scfg.mode == "masked":
-            return _masked_grads(params, inputs, targets, alloc, cfg, scfg, attn_impl)
+            return _masked_grads(params, inputs, targets, alloc, grad_fn, gdt)
         if inputs.shape[0] % n_rank_shards:
             raise ValueError(
                 f"while-mode batch has R={inputs.shape[0]} rank rows, not divisible by "
@@ -361,7 +351,7 @@ def build_train_step(
             # Params enter SHARDED per pspecs; one gather inside, gradients
             # leave as shards (out_specs = pspecs).
             body = compat.shard_map(
-                lambda p, x, y, a: _gathered_while_grads(p, x, y, a, cfg, scfg, pspecs, attn_impl),
+                lambda p, x, y, a: _gathered_while_grads(p, x, y, a, grad_fn, gdt, scfg, pspecs),
                 mesh,
                 in_specs=(pspecs,) + batch_specs,
                 out_specs=(pspecs, P(), P()),
@@ -371,7 +361,7 @@ def build_train_step(
             # Params enter replicated (P()); non-allocation shards
             # redundantly compute identical grads.
             body = compat.shard_map(
-                lambda p, x, y, a: _while_grads(p, x, y, a, cfg, scfg, attn_impl),
+                lambda p, x, y, a: _while_grads(p, x, y, a, grad_fn, gdt, scfg),
                 mesh,
                 in_specs=(P(),) + batch_specs,
                 out_specs=(P(), P(), P()),
@@ -380,11 +370,8 @@ def build_train_step(
         return body(params, inputs, targets, alloc)
 
     def step(state, batch):
-        with kops.lowering_for(platform), attn_lib.record_paths() as tally:
-            out = _step(state, batch)
-        attention_paths.clear()
-        attention_paths.update(tally)
-        return out
+        with kops.lowering_for(platform):
+            return _step(state, batch)
 
     def _step(state, batch):
         # host-side guard for eager (jit=False) callers; a no-op on tracers.
@@ -423,7 +410,6 @@ def build_train_step(
         return new_state, metrics
 
     if not jit:
-        step.attention_paths = attention_paths
         return step
     jitted = jax.jit(step, donate_argnums=(0,))
 
@@ -431,7 +417,6 @@ def build_train_step(
         _host_check_alloc(batch.get("alloc"), scfg.w_max)
         return jitted(state, batch)
 
-    checked_step.attention_paths = attention_paths
     return checked_step
 
 

@@ -8,7 +8,6 @@ in ``ref.py`` and a jit'd dispatch wrapper in ``ops.py``:
 * ``splash_attention`` — causal GQA training attention with its backward, on
   JAX's shipped splash kernel (oracle: naive attention in ``models.attention``)
 * ``rwkv6_scan``      — chunked RWKV6 recurrence (MXU-shaped)
-* ``weighted_accum``  — fused axpy for the gradient-accumulation loop
 """
 
 from repro.kernels import ops, ref

@@ -16,13 +16,11 @@ import contextlib
 import contextvars
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import paged_attention as _pa
 from repro.kernels import rwkv6_scan as _rw
 from repro.kernels import splash_attention as _sa
-from repro.kernels import weighted_accum as _wa
 
 __all__ = [
     "flash_attention",
@@ -31,8 +29,6 @@ __all__ = [
     "pallas_interpret",
     "rwkv6_scan",
     "splash_attention",
-    "weighted_accum",
-    "weighted_accum_tree",
 ]
 
 _PLATFORM: contextvars.ContextVar[str | None] = contextvars.ContextVar("pallas_platform", default=None)
@@ -89,11 +85,3 @@ def paged_attention(q, k_pool, v_pool, pages, lengths, k_scale=None, v_scale=Non
 
 def rwkv6_scan(r, k, v, w, u, s0=None, chunk: int = 32, interpret=None):
     return _rw.rwkv6_scan(r, k, v, w, u, s0, chunk=chunk, interpret=_resolve(interpret))
-
-
-def weighted_accum(acc, g, scale, interpret=None):
-    return _wa.weighted_accum(acc, g, jnp.asarray(scale, jnp.float32), interpret=_resolve(interpret))
-
-
-def weighted_accum_tree(acc_tree, g_tree, scale, interpret=None):
-    return _wa.weighted_accum_tree(acc_tree, g_tree, scale, interpret=_resolve(interpret))

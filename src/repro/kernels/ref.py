@@ -10,7 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention_ref", "paged_attention_ref", "rwkv6_scan_ref", "weighted_accum_ref"]
+__all__ = ["flash_attention_ref", "paged_attention_ref", "rwkv6_scan_ref"]
 
 NEG_INF = -2.0e38
 
@@ -119,10 +119,3 @@ def rwkv6_scan_ref(r, k, v, w, u, s0=None):
     xs = tuple(jnp.moveaxis(a, 1, 0) for a in (r, k, v, w))
     s_end, ys = jax.lax.scan(step, s0, xs)
     return jnp.moveaxis(ys, 0, 1), s_end
-
-
-def weighted_accum_ref(acc: jnp.ndarray, g: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
-    """acc + scale * g, computed in fp32, cast back to acc.dtype."""
-    return (acc.astype(jnp.float32) + scale.astype(jnp.float32) * g.astype(jnp.float32)).astype(
-        acc.dtype
-    )

@@ -10,8 +10,8 @@ Four implementations (numerically equivalent, tested):
 * ``splash``  — training on a TPU: the shipped Pallas splash kernel with its
   own backward (``repro.kernels.splash_attention``).  The train step picks it
   where it is built (``dist/hetero_step.py``); :func:`attention_train` then
-  falls back to ``blocked`` per call for what the kernel cannot express
-  (given positions, a sequence that is not a multiple of 128).
+  falls back to ``blocked`` per call for a sequence that is not a multiple
+  of 128.
 * ``flash``   — Pallas TPU forward kernel (``repro.kernels.flash_attention``)
   for serving prefill.
 
@@ -24,8 +24,6 @@ q is viewed as (B, S, Hkv, G, Dh) and contracted against k (B, S, Hkv, Dh).
 
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import dataclasses
 from functools import partial
 from typing import Any
@@ -44,8 +42,6 @@ __all__ = [
     "attention_train",
     "attention_decode",
     "attention_prefill",
-    "record_paths",
-    "layers_per_call",
 ]
 
 NEG_INF = -2.0e38  # large finite; avoids NaN from (-inf) - (-inf)
@@ -222,59 +218,22 @@ def _attend(q, k, v, q_pos, k_pos, cfg: ModelConfig, window, impl: str):
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
-_PATHS: contextvars.ContextVar[dict | None] = contextvars.ContextVar("attention_paths", default=None)
-_LAYERS: contextvars.ContextVar[int] = contextvars.ContextVar("attention_layers", default=1)
-
-
-@contextlib.contextmanager
-def record_paths():
-    """Tally which path each training attention layer lowers to while a
-    program traces inside the block: yields ``{"splash": n, "blocked": m}``
-    (other impls appear under their own names)."""
-    tally = {"splash": 0, "blocked": 0}
-    token = _PATHS.set(tally)
-    try:
-        yield tally
-    finally:
-        _PATHS.reset(token)
-
-
-@contextlib.contextmanager
-def layers_per_call(n: int):
-    """Inside the block one traced attention call stands for ``n`` layers
-    (the body of a ``lax.scan`` over a stack of ``n`` layers)."""
-    token = _LAYERS.set(n)
-    try:
-        yield
-    finally:
-        _LAYERS.reset(token)
-
-
-def _train_path(impl: str, seq: int, positions) -> str:
-    """The splash kernel takes contiguous positions and a sequence that is a
-    multiple of 128 (causal, with or without window and softcap); anything
-    else trains on the blocked scan."""
-    if impl == "splash" and (positions is not None or seq % 128):
-        return "blocked"
-    return impl
-
-
 def attention_train(
     p: dict,
     x: jnp.ndarray,
     cfg: ModelConfig,
     attn_type: str,
-    positions: jnp.ndarray | None = None,
     impl: str = "blocked",
 ) -> jnp.ndarray:
-    """Full-sequence (training / prefill) attention. x: (B, S, d) -> (B, S, d)."""
+    """Full-sequence (training / prefill) attention. x: (B, S, d) -> (B, S, d).
+
+    The splash kernel takes a sequence that is a multiple of 128 (causal,
+    with or without window and softcap); any other length trains on the
+    blocked scan."""
     B, S, _ = x.shape
-    impl = _train_path(impl, S, positions)
-    tally = _PATHS.get()
-    if tally is not None:
-        tally[impl] = tally.get(impl, 0) + _LAYERS.get()
-    if positions is None:
-        positions = jnp.arange(S)
+    if impl == "splash" and S % 128:
+        impl = "blocked"
+    positions = jnp.arange(S)
     window = cfg.sliding_window if attn_type == "local" else None
     q, k, v = _qkv(p, x, cfg, positions)
     out = _attend(q, k, v, positions, positions, cfg, window, impl)

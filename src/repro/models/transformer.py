@@ -239,8 +239,7 @@ def forward(
                 h, a = body_fn(block_params, h)
                 return (_wsc(h, sh.get("h")), aux + a), None
 
-            with attn_lib.layers_per_call(cfg.n_repeats):
-                (h, aux_total), _ = jax.lax.scan(scan_body, (h, aux_total), params["body"])
+            (h, aux_total), _ = jax.lax.scan(scan_body, (h, aux_total), params["body"])
     if cfg.tail_layers:
         tail_fn = _maybe_remat(_pattern_fn(cfg, cfg.tail_layers, attn_impl, wkv_impl, sh.get("h")), cfg)
         h, a = tail_fn(params["tail"], h)

@@ -134,14 +134,6 @@ class TrainObs(_ObsBase):
         self.metrics.counter("train.micro_passes_computed").inc(computed)
         self.metrics.counter("train.micro_passes_trained").inc(trained)
 
-    def on_attention_paths(self, paths) -> None:
-        """The step's attention layers by the path they lowered to
-        (``train.attention_path.<path>`` gauges)."""
-        if self.metrics is None:
-            return
-        for path, n in paths.items():
-            self.metrics.gauge(f"train.attention_path.{path}").set(n)
-
     def on_flags(self, epoch, step_end, flags) -> None:
         if not self.enabled:
             return

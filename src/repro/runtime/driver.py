@@ -234,13 +234,6 @@ class ElasticTrainer:
             self._restore()
         self._rebuild()
 
-    @property
-    def attention_paths(self) -> dict[str, int]:
-        """The path each attention layer of the current step lowered to
-        (``{"splash": 32, "blocked": 0}``), tallied when the step traced;
-        empty before its first call."""
-        return dict(self.step_fn.attention_paths)
-
     # -- membership-dependent construction ------------------------------------
 
     def _rebuild(self) -> None:
@@ -648,7 +641,6 @@ class ElasticTrainer:
                     self.micro_passes_computed += computed
                     self.micro_passes_trained += trained
                     self.obs.on_micro_passes(computed, trained)
-                    self.obs.on_attention_paths(self.step_fn.attention_paths)
                     self.step_i += 1
                     self.agg_index += 1
                     steps_run += 1

@@ -65,7 +65,7 @@ def test_while_equals_masked_equals_reference():
         s1, m1 = build_train_step(cfg, sw, mesh)(jax.tree.map(lambda x: x.copy(), state), batch)
         s2, m2 = build_train_step(cfg, sm, mesh)(jax.tree.map(lambda x: x.copy(), state), batch)
         # reference
-        gf = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg, sw), has_aux=True)
+        gf = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg), has_aux=True)
         toks, lsum = 0.0, 0.0
         for r in range(R):
             for j in range(int(alloc[r])):
@@ -123,7 +123,7 @@ def test_masked_below_buffer_depth_equals_reference():
             return build_train_step(cfg, modes[name], mesh)(jax.tree.map(lambda x: x.copy(), start), batch)
 
         alloc = [1, 2, 1, 0]  # max 2 < w_max 4
-        gf = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg, modes["masked"]), has_aux=True)
+        gf = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg), has_aux=True)
         toks, lsum, gsum = 0.0, 0.0, jax.tree.map(jnp.zeros_like, state["params"])
         for r in range(R):
             for j in range(alloc[r]):
@@ -153,19 +153,19 @@ def test_masked_below_buffer_depth_equals_reference():
 def test_masked_grads_stop_at_the_largest_allocation():
     """One rank, w_max 8, allocation 4: the masked accumulation equals an
     explicit scan over the four live slots, bit for bit."""
-    from repro.dist.hetero_step import HeteroStepConfig, _grad_fn, _masked_grads, _zero_carry
+    from repro.dist.hetero_step import _masked_grads, _micro_loss_sum, _zero_carry
     from repro.models import ModelConfig, transformer
 
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
                       d_ff=64, vocab_size=101, compute_dtype="bfloat16", remat=False)
-    scfg = HeteroStepConfig(w_max=8, micro_bs=2, seq_len=16, mode="masked")
+    grad_fn = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg), has_aux=True)
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     inputs = jax.random.randint(jax.random.PRNGKey(7), (1, 8, 2, 16), 0, 101)
     targets = jax.random.randint(jax.random.PRNGKey(8), (1, 8, 2, 16), 0, 101)
     alloc = jnp.array([4], jnp.int32)
-    got = jax.jit(lambda p, x, y, a: _masked_grads(p, x, y, a, cfg, scfg, "blocked"))(params, inputs, targets, alloc)
+    got = jax.jit(lambda p, x, y, a: _masked_grads(p, x, y, a, grad_fn, jnp.float32))(params, inputs, targets, alloc)
 
-    vgrad = jax.vmap(_grad_fn(cfg, scfg, "blocked"), in_axes=(None, 0, 0))
+    vgrad = jax.vmap(grad_fn, in_axes=(None, 0, 0))
 
     def four_slots(p, x, y):
         def slot(carry, xs):
@@ -216,7 +216,7 @@ def test_while_gather_fsdp_equals_masked_equals_reference():
         s2, m2 = build_train_step(cfg, sm, mesh)(jax.tree.map(lambda x: x.copy(), state), batch)
         s3, m3 = build_train_step(cfg, sr, mesh)(jax.tree.map(lambda x: x.copy(), state), batch)
         # reference loss over the union of live microbatches
-        gf = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg, sg), has_aux=True)
+        gf = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg), has_aux=True)
         toks, lsum = 0.0, 0.0
         for r in range(R):
             for j in range(int(alloc[r])):

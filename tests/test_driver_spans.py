@@ -14,9 +14,11 @@ import glob
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.jaxpr_walk import find_eqns
 from repro.dist import HeteroStepConfig, micro_passes
 from repro.runtime.driver import DriverConfig, ElasticTrainer
 
@@ -216,12 +218,13 @@ def test_counters_follow_the_allocation_through_a_rebuild(long_traced):
     assert snap["counters"]["train.micro_passes_trained"] == 8 * 4
 
 
-def test_attention_paths_on_the_trainer_and_in_the_snapshot(long_traced):
-    """On the CPU every attention layer trains on the blocked scan; the
-    trainer and the ``--metrics-out`` snapshot both carry the tally, through
-    a rebuild that traced the step again."""
-    tr, _, snap = long_traced
-    n = tr.model_cfg.n_layers
-    assert tr.attention_paths == {"splash": 0, "blocked": n}
-    assert snap["gauges"]["train.attention_path.blocked"]["value"] == n
-    assert snap["gauges"]["train.attention_path.splash"]["value"] == 0
+def test_rebuilt_trainer_step_holds_no_pallas_call_on_the_cpu(long_traced):
+    """On the CPU every attention layer trains on the blocked scan: the step
+    the membership rebuild made holds matmuls and no Pallas call."""
+    tr, _, _ = long_traced
+    R, s = len(tr.alloc), tr.scfg
+    rows = jax.ShapeDtypeStruct((R, s.w_max, s.micro_bs, s.seq_len), jnp.int32)
+    batch = {"inputs": rows, "targets": rows, "alloc": jax.ShapeDtypeStruct((R,), jnp.int32)}
+    closed = jax.make_jaxpr(tr.step_fn)(tr.state, batch)
+    assert find_eqns(closed, "dot_general")
+    assert not find_eqns(closed, "pallas_call")

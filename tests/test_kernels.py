@@ -13,14 +13,11 @@ from repro.kernels.ops import (
     paged_attention,
     pallas_interpret,
     rwkv6_scan,
-    weighted_accum,
-    weighted_accum_tree,
 )
 from repro.kernels.ref import (
     flash_attention_ref,
     paged_attention_ref,
     rwkv6_scan_ref,
-    weighted_accum_ref,
 )
 
 KEY = jax.random.PRNGKey(0)
@@ -204,30 +201,3 @@ def test_rwkv6_state_carry_composes():
     y2, s2 = rwkv6_scan(r[:, h:], k[:, h:], v[:, h:], w[:, h:], u, s1, chunk=16)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)), np.asarray(y_full), rtol=3e-4, atol=3e-4)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s_full), rtol=3e-4, atol=3e-4)
-
-
-# ---------------------------------------------------------------------------
-# weighted accumulation
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize(
-    "shape,dtype",
-    [((1000,), jnp.float32), ((33, 77), jnp.float32), ((8, 128), jnp.bfloat16), ((5, 3, 7), jnp.float32)],
-)
-def test_weighted_accum_matches_ref(shape, dtype):
-    a = jax.random.normal(KEY, shape).astype(dtype)
-    g = jax.random.normal(jax.random.PRNGKey(1), shape).astype(dtype)
-    out = weighted_accum(a, g, 0.37)
-    ref = weighted_accum_ref(a, g, jnp.float32(0.37))
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=1e-5, atol=1e-5
-    )
-
-
-def test_weighted_accum_tree():
-    tree_a = {"x": jnp.ones((64,)), "y": {"z": jnp.zeros((4, 4))}}
-    tree_g = {"x": jnp.full((64,), 2.0), "y": {"z": jnp.ones((4, 4))}}
-    out = weighted_accum_tree(tree_a, tree_g, 0.5)
-    np.testing.assert_allclose(np.asarray(out["x"]), 2.0)
-    np.testing.assert_allclose(np.asarray(out["y"]["z"]), 0.5)

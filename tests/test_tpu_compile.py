@@ -102,8 +102,7 @@ def _train_step_text(cfg, scfg, devices):
         "targets": jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rows),
         "alloc": jax.ShapeDtypeStruct((len(devices),), jnp.int32, sharding=rows),
     }
-    text = jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile().as_text()
-    return text, step.attention_paths
+    return jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile().as_text()
 
 
 SPLASH_KERNELS = ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals", "splash_mqa_dkv_no_residuals")
@@ -118,10 +117,9 @@ def test_smollm_masked_train_step_compiles_with_splash_for_v5e(topo):
 
     cfg = get_config("smollm-360m")
     scfg = HeteroStepConfig(w_max=8, micro_bs=1, seq_len=2048, mode="masked")
-    text, paths = _train_step_text(cfg, scfg, topo.devices[:1])
+    text = _train_step_text(cfg, scfg, topo.devices[:1])
     assert "tpu_custom_call" in text
     assert all(name in text for name in SPLASH_KERNELS)
-    assert paths == {"splash": cfg.n_layers, "blocked": 0}
 
 
 def test_while_train_step_runs_splash_per_chip_on_four_v5e(topo):
@@ -134,6 +132,5 @@ def test_while_train_step_runs_splash_per_chip_on_four_v5e(topo):
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=256, n_heads=4, n_kv_heads=2,
                       d_ff=512, vocab_size=512)
     scfg = HeteroStepConfig(w_max=2, micro_bs=1, seq_len=256, mode="while")
-    text, paths = _train_step_text(cfg, scfg, topo.devices[:4])
+    text = _train_step_text(cfg, scfg, topo.devices[:4])
     assert all(name in text for name in SPLASH_KERNELS)
-    assert paths == {"splash": 2, "blocked": 0}
