@@ -43,6 +43,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import Literal
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -64,7 +65,20 @@ from repro.optim import (
     sgd_update,
 )
 
-__all__ = ["HeteroStepConfig", "init_train_state", "build_train_step", "micro_passes", "train_attention"]
+__all__ = [
+    "HeteroStepConfig",
+    "init_train_state",
+    "build_train_step",
+    "layer_remat",
+    "micro_passes",
+    "train_attention",
+]
+
+# The step keeps no layer residuals for recompute only while its reckoned bytes
+# stay under this share of the device's memory: the reckoning leaves out XLA's
+# temporaries (fusion buffers, the optimizer's intermediates) and the
+# allocator's fragmentation, which the last quarter holds.
+NO_REMAT_SHARE = 0.75
 
 
 @dataclasses.dataclass(frozen=True)
@@ -282,6 +296,75 @@ def train_attention(platform: str, n_devices: int, mode: str) -> str:
     return "blocked"
 
 
+def _bytes(tree) -> int:
+    return sum(x.size * jnp.dtype(x.dtype).itemsize for x in jax.tree.leaves(tree))
+
+
+def _bytes_limit(mesh) -> int | None:
+    """The memory of the mesh's first device, or None where the device has no
+    statistics (the CPU, a described device, a runtime that keeps none)."""
+    try:
+        stats = mesh.devices.flat[0].memory_stats()
+    except jax.errors.JaxRuntimeError:
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+# an abstract raw key: a concrete one, made while the step is traced, would
+# stage a key op into the step's program
+_KEY = jax.ShapeDtypeStruct((2,), jnp.uint32)
+
+
+def _residual_bytes(cfg: ModelConfig, micro_bs: int, seq_len: int, attn_impl: str) -> int:
+    """Bytes one microbatch's backward reads from its forward with ``cfg``'s
+    remat setting: the residuals ``jax.vjp`` keeps, less the parameters it
+    passes through (live anyway).  A trace of the loss only: the scan over the
+    layer stack traces its body once, whatever the depth."""
+    params = jax.eval_shape(lambda k: transformer.init_params(cfg, k), _KEY)
+    tokens = jax.ShapeDtypeStruct((micro_bs, seq_len), jnp.int32)
+
+    def pullback(p, x, y):
+        return jax.vjp(lambda p: _micro_loss_sum(p, x, y, cfg, attn_impl)[0], p)[1]
+
+    closed = jax.make_jaxpr(pullback)(params, tokens, tokens)
+    seen = set(closed.jaxpr.invars)
+    total = 0
+    for v, aval in zip(closed.jaxpr.outvars, closed.out_avals):
+        if not isinstance(v, Literal):
+            if v in seen:
+                continue
+            seen.add(v)
+        total += aval.size * aval.dtype.itemsize
+    return total
+
+
+def layer_remat(cfg: ModelConfig, scfg: HeteroStepConfig, mesh, ranks: int) -> tuple[bool, int]:
+    """Whether the step recomputes each layer's forward in its backward
+    (``cfg.remat``), and the residual bytes one device would hold without it.
+
+    ``ranks`` is the batch's rank rows.  Masked mode vmaps the rows a device
+    holds, so their microbatches keep residuals at once; while mode runs one
+    microbatch at a time.  Without remat a device holds the state (parameters
+    and optimizer moments), the accumulation carry, each row's microbatch
+    gradient and its residuals, all reckoned whole (FSDP shards only lower
+    them).  The step drops remat where that total is under
+    :data:`NO_REMAT_SHARE` of the device's memory, and keeps ``cfg``'s
+    setting where it is not or the memory is unknown."""
+    no_remat = dataclasses.replace(cfg, remat=False)
+    platform = mesh.devices.flat[0].platform
+    attn_impl = train_attention(platform, mesh.devices.size, scfg.mode)
+    rows = -(-ranks // int(dict(mesh.shape)[scfg.alloc_axis])) if scfg.mode == "masked" else 1
+    residuals = rows * _residual_bytes(no_remat, scfg.micro_bs, scfg.seq_len, attn_impl)
+    limit = _bytes_limit(mesh)
+    if not cfg.remat or limit is None:
+        return cfg.remat, residuals
+    state = jax.eval_shape(lambda k: init_train_state(cfg, scfg, k), _KEY)
+    params = _bytes(state["params"])
+    n_params = sum(x.size for x in jax.tree.leaves(state["params"]))
+    total = _bytes(state) + n_params * jnp.dtype(scfg.grad_dtype).itemsize + rows * params + residuals
+    return total >= NO_REMAT_SHARE * limit, residuals
+
+
 # ---------------------------------------------------------------------------
 # the step
 # ---------------------------------------------------------------------------
@@ -305,12 +388,12 @@ def build_train_step(
 
     Attention is chosen here from the mesh (:func:`train_attention`), and
     the one microbatch gradient function every accumulation body runs is
-    built here with it.
+    built with it when the step is traced, on ``cfg`` without remat where
+    :func:`layer_remat` finds the batch's residuals fit the device.
     """
     scfg.validate(mesh)
     platform = mesh.devices.flat[0].platform
     attn_impl = train_attention(platform, mesh.devices.size, scfg.mode)
-    grad_fn = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, cfg, attn_impl), has_aux=True)
     gdt = jnp.dtype(scfg.grad_dtype)
     lr_fn = lr_fn or constant(scfg.lr)
     if scfg.optimizer == "adamw":
@@ -333,6 +416,9 @@ def build_train_step(
         sspecs = pspecs = None
 
     def global_grads(params, inputs, targets, alloc):
+        remat, _ = layer_remat(cfg, scfg, mesh, inputs.shape[0])
+        step_cfg = cfg if remat else dataclasses.replace(cfg, remat=False)
+        grad_fn = jax.value_and_grad(lambda p, x, y: _micro_loss_sum(p, x, y, step_cfg, attn_impl), has_aux=True)
         if scfg.mode == "masked":
             return _masked_grads(params, inputs, targets, alloc, grad_fn, gdt)
         if inputs.shape[0] % n_rank_shards:

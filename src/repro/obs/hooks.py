@@ -54,7 +54,8 @@ class TrainObs(_ObsBase):
     """ElasticTrainer hooks: per-worker compute/wait/collective spans per
     aggregation (simulated timing) or one span per measured step, allocation
     counters, membership/checkpoint instants, fault windows as spans,
-    straggler flags, collective bytes, microbatch passes."""
+    straggler flags, collective bytes, microbatch passes, the step's remat
+    choice."""
 
     def __init__(self, trace_out=None, metrics_out=None, tracer=None, metrics=None) -> None:
         super().__init__(trace_out, metrics_out, tracer, metrics)
@@ -133,6 +134,15 @@ class TrainObs(_ObsBase):
             return
         self.metrics.counter("train.micro_passes_computed").inc(computed)
         self.metrics.counter("train.micro_passes_trained").inc(trained)
+
+    def on_layer_remat(self, remat, residual_bytes) -> None:
+        """The built step's remat choice (``dist.hetero_step.layer_remat``):
+        1 where it recomputes each layer's forward, and the residual bytes a
+        device would hold without it."""
+        if self.metrics is None:
+            return
+        self.metrics.gauge("train.layer_remat").set(int(remat))
+        self.metrics.gauge("train.residual_bytes").set(residual_bytes)
 
     def on_flags(self, epoch, step_end, flags) -> None:
         if not self.enabled:

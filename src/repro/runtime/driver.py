@@ -70,7 +70,7 @@ from repro.core import (
     static_allocation,
 )
 from repro.data import HeteroBatcher, SyntheticLM
-from repro.dist import HeteroStepConfig, build_train_step, init_train_state, micro_passes
+from repro.dist import HeteroStepConfig, build_train_step, init_train_state, layer_remat, micro_passes
 from repro.dist.collectives import ring_allreduce_bytes
 from repro.dist.sharding import state_specs
 from repro.obs import TrainObs
@@ -277,6 +277,8 @@ class ElasticTrainer:
             lr_fn=warmup_cosine(cfg.lr, 10, cfg.steps),
             jit=True,
         )
+        if self.obs.metrics is not None:  # the reckoning is a trace: only where it is reported
+            self.obs.on_layer_remat(*layer_remat(self.model_cfg, self.scfg, self.mesh, n))
         self.batcher = HeteroBatcher(self.dataset, n, cfg.micro_bs, self.w_max, seed=cfg.seed)
         self._rebuild_monitoring()
 

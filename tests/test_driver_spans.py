@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.analysis.jaxpr_walk import find_eqns
-from repro.dist import HeteroStepConfig, micro_passes
+from repro.dist import HeteroStepConfig, layer_remat, micro_passes
 from repro.runtime.driver import DriverConfig, ElasticTrainer
 
 # 2 ranks at 3:1 of C=4, w_max 4; 4 aggregations per epoch, so the loop pulls
@@ -228,3 +228,16 @@ def test_rebuilt_trainer_step_holds_no_pallas_call_on_the_cpu(long_traced):
     closed = jax.make_jaxpr(tr.step_fn)(tr.state, batch)
     assert find_eqns(closed, "dot_general")
     assert not find_eqns(closed, "pallas_call")
+
+
+def test_remat_gauges_follow_the_rows_through_a_rebuild(long_traced):
+    """Each build reports the step's remat choice and its residual bytes:
+    the CPU reports no memory, so the smoke configuration's setting stays;
+    both ranks' rows and then all three fold onto the one device, so the
+    residuals grow by half at the rebuild."""
+    tr, _, snap = long_traced
+    remat, one_row = layer_remat(tr.model_cfg, tr.scfg, tr.mesh, 1)
+    assert remat is tr.model_cfg.remat
+    g = snap["gauges"]
+    assert g["train.layer_remat"] == {"value": int(remat), "min": int(remat), "max": int(remat)}
+    assert g["train.residual_bytes"] == {"value": 3 * one_row, "min": 2 * one_row, "max": 3 * one_row}

@@ -410,6 +410,19 @@ def test_train_obs_micro_pass_counters(tmp_path):
     assert off.metrics is None
 
 
+def test_train_obs_layer_remat_gauges(tmp_path):
+    obs = TrainObs(metrics_out=str(tmp_path / "m.json"))
+    obs.on_layer_remat(True, 1000)
+    obs.on_layer_remat(False, 4000)
+    obs.close()
+    g = json.loads((tmp_path / "m.json").read_text())["gauges"]
+    assert g["train.layer_remat"] == {"value": 0, "min": 0, "max": 1}
+    assert g["train.residual_bytes"] == {"value": 4000, "min": 1000, "max": 4000}
+    off = TrainObs()
+    off.on_layer_remat(True, 1000)  # disabled: no registry, no error
+    assert off.metrics is None
+
+
 def test_disabled_obs_bundles_do_no_work():
     obs = TrainObs()  # no outputs -> disabled
     assert not obs.enabled
